@@ -12,3 +12,6 @@ def r32(num_classes=10):
 
 def r56(num_classes=100):
     return ResNetConfig(depth=56, num_classes=num_classes)
+
+
+MODELS = {"resnet8": r8, "resnet32": r32, "resnet56": r56}

@@ -27,24 +27,27 @@ def inverse_permutation(perm):
     return jnp.argsort(perm)
 
 
-def _permute_leaf(x, perm, use_kernel, interpret):
+def _permute_leaf(x, perm, use_kernel):
     if use_kernel:
         from repro.kernels.collector_permute.ops import collector_permute_ad
-        return collector_permute_ad(x, perm, interpret)
+        from repro.kernels.platform import interpret
+        return collector_permute_ad(x, perm, interpret())
     return jnp.take(x, perm, axis=0)
 
 
-def shuffle(tree, perm, *, use_kernel=False, interpret=True):
-    """Apply ``perm`` along axis 0 of every leaf (smashed data + labels)."""
+def shuffle(tree, perm, *, use_kernel=False):
+    """Apply ``perm`` along axis 0 of every leaf (smashed data + labels).
+    ``use_kernel`` runs the Pallas gather: compiled on TPU, in interpret
+    mode elsewhere."""
     return jax.tree_util.tree_map(
-        lambda x: _permute_leaf(x, perm, use_kernel, interpret), tree)
+        lambda x: _permute_leaf(x, perm, use_kernel), tree)
 
 
-def deshuffle(tree, perm, *, use_kernel=False, interpret=True):
+def deshuffle(tree, perm, *, use_kernel=False):
     """Inverse of ``shuffle`` — routes gradients back to source clients."""
     inv = inverse_permutation(perm)
     return jax.tree_util.tree_map(
-        lambda x: _permute_leaf(x, inv, use_kernel, interpret), tree)
+        lambda x: _permute_leaf(x, inv, use_kernel), tree)
 
 
 def collect(per_client_tree):

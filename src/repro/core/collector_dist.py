@@ -105,7 +105,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.kernels._compat import get_shard_map
+from repro.kernels.platform import interpret
 from repro.core.wire import (SCALE_BYTES, SCALE_LANES, WIRE_DTYPES,
                              is_quantized, pack_scales, resolve_wire_dtype,
                              unpack_scales, wire_itemsize)
@@ -583,17 +583,12 @@ def build_submesh_route_plans(sub_perm, slice_index, n_shards, slice_size):
 
 
 def _shard_map_maybe_norep(local, *, mesh, in_specs, out_specs, norep):
-    shard_map = get_shard_map()
     kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
     if norep:
         # pallas_call has no replication rule; the kernel only touches
-        # per-shard rows so skipping the check is sound. The flag was
-        # renamed check_rep -> check_vma across jax versions.
-        try:
-            return shard_map(local, **kwargs, check_rep=False)
-        except TypeError:
-            return shard_map(local, **kwargs, check_vma=False)
-    return shard_map(local, **kwargs)
+        # per-shard rows so skipping the check is sound
+        return jax.shard_map(local, **kwargs, check_vma=False)
+    return jax.shard_map(local, **kwargs)
 
 
 def _gather_rows(x, idx, *, use_kernel, bucket_shape=None):
@@ -604,11 +599,10 @@ def _gather_rows(x, idx, *, use_kernel, bucket_shape=None):
     if use_kernel and jnp.issubdtype(x.dtype, jnp.floating):
         from repro.kernels.collector_permute.ops import (bucket_permute,
                                                          unbucket_permute)
-        interpret = jax.default_backend() != "tpu"
         if bucket_shape is not None:
             return bucket_permute(x, idx.reshape(bucket_shape),
-                                  interpret=interpret)
-        return unbucket_permute(x, idx, interpret=interpret)
+                                  interpret=interpret())
+        return unbucket_permute(x, idx, interpret=interpret())
     return x[idx]
 
 
@@ -636,7 +630,7 @@ def _quant_send_payload(x_loc, send_idx, S, cap, wire, use_kernel):
         from repro.kernels.quant_permute.ops import quant_bucket_permute
         q, scales = quant_bucket_permute(
             x_loc, send_idx.reshape(S, cap), wire_dtype=wire,
-            interpret=jax.default_backend() != "tpu")
+            interpret=interpret())
     else:
         from repro.kernels.quant_permute.ref import quant_bucket_permute_ref
         x2 = x_loc.reshape(x_loc.shape[0], -1)
@@ -657,7 +651,7 @@ def _dequant_recv_payload(flat, recv_idx, wire, out_dtype, feat_shape,
         from repro.kernels.quant_permute.ops import dequant_unbucket_permute
         out2 = dequant_unbucket_permute(
             q, scales, recv_idx, out_dtype=jnp.dtype(out_dtype),
-            interpret=jax.default_backend() != "tpu")
+            interpret=interpret())
     else:
         from repro.kernels.quant_permute.ref import (
             dequant_unbucket_permute_ref)

@@ -65,10 +65,10 @@ def make_resnet_split(cfg, policy=None):
     if policy is None:
         loss_fn = softmax_cross_entropy
     elif policy.fused():
+        from repro.kernels.platform import interpret
         from repro.kernels.softmax_xent import ops as _xent
         def loss_fn(logits, y):
-            return _xent.softmax_xent(logits, y,
-                                      interpret=policy.kernel_interpret)
+            return _xent.softmax_xent(logits, y, interpret=interpret())
     else:
         loss_fn = softmax_cross_entropy
 

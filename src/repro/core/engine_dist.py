@@ -76,13 +76,24 @@ holds an equal contiguous slice of the flattened shard axis)
     """
     num_shards = num_shards or len(jax.devices())
     if pods is None:
-        return jax.make_mesh((num_shards,), (axis,))
+        return make_auto_mesh((num_shards,), (axis,))
     if pods < 1 or num_shards % pods:
         raise ValueError(
             f"pods={pods} must be >= 1 and divide num_shards="
             f"{num_shards} (each pod holds an equal contiguous slice of "
             f"the flattened shard axis)")
-    return jax.make_mesh((pods, num_shards // pods), (pod_axis, axis))
+    return make_auto_mesh((pods, num_shards // pods), (pod_axis, axis))
+
+
+def make_auto_mesh(shape, names, *, devices=None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``: shardings are
+    placed by ``NamedSharding``/``shard_map`` and propagated by the
+    partitioner, never carried in array types (jax's default Explicit axes
+    reject the vmapped per-client conv's reshape). Every mesh of the
+    repo is built here."""
+    return jax.make_mesh(shape, names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names),
+                         devices=devices)
 
 
 def collector_axis(mesh, *, axis="data", pod_axis="pod"):
@@ -382,7 +393,7 @@ def sfpl_epoch_sharded(key, st, data, split: SplitModel, opt_c, opt_s, *,
                       participation=part_host, wire_dtype=wire_dtype,
                       wire_dtype_bwd=wire_dtype_bwd)
     placement = RD.DataMesh(mesh, axis)
-    return RD.sfpl_round(
+    st, losses = RD.sfpl_round(
         key, st, data, split, opt_c, opt_s, num_clients=num_clients,
         batch_size=batch_size, bn_mode=bn_mode,
         collector=placement.collector(
@@ -392,6 +403,7 @@ def sfpl_epoch_sharded(key, st, data, split: SplitModel, opt_c, opt_s, *,
             submesh=collector_submesh, wire_dtype=wire_dtype,
             wire_dtype_bwd=wire_dtype_bwd),
         participation=participation)
+    return placement.constrain_state(st), losses
 
 
 def make_sfpl_epoch_sharded(split: SplitModel, opt_c, opt_s, data, *,

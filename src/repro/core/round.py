@@ -75,7 +75,7 @@ from repro.core.collector_dist import (
     mesh_axis_size, pair_capacity, plan_exchange, plan_exchange_complete,
     plan_exchange_issue, plan_payload_bytes, plan_shuffle,
     submesh_slice_size, uniform_auto_slack)
-from repro.kernels._compat import auto_use_kernel
+from repro.kernels.platform import auto_use_kernel
 
 logger = logging.getLogger(__name__)
 
@@ -150,20 +150,29 @@ class DataMesh:
     def n_shards(self):
         return mesh_axis_size(self.mesh, self.axis)
 
-    def place_state(self, st):
-        """Place an ``init_dcml_state`` tree: client-stacked leaves sharded
-        on their leading (client) axis, server leaves replicated."""
+    def state_shardings(self, st):
+        """Sharding of each subtree of an ``init_dcml_state`` tree:
+        client-stacked leaves on their leading (client) axis, server
+        leaves replicated."""
         shard = NamedSharding(self.mesh, P(self.axis))
         repl = NamedSharding(self.mesh, P())
-        put = lambda t, s: jax.tree_util.tree_map(
-            lambda a: _global_put(a, s), t)
-        return dict(
-            st,
-            cp=put(st["cp"], shard), cbn=put(st["cbn"], shard),
-            copt=put(st["copt"], shard),
-            sp=put(st["sp"], repl), sbn=put(st["sbn"], repl),
-            sopt=put(st["sopt"], repl),
-            step=_global_put(st["step"], repl))
+        return {k: shard if k in ("cp", "cbn", "copt") else repl
+                for k in st}
+
+    def place_state(self, st):
+        """Place an ``init_dcml_state`` tree per ``state_shardings``."""
+        sh = self.state_shardings(st)
+        return {k: jax.tree_util.tree_map(lambda a: _global_put(a, sh[k]), v)
+                for k, v in st.items()}
+
+    def constrain_state(self, st):
+        """Pin a traced state tree to ``state_shardings``: an epoch then
+        returns its state in the layout it took it in (the epoch-end
+        FedAvg would otherwise leave the client params replicated), so
+        the next epoch reuses the same executable."""
+        sh = self.state_shardings(st)
+        return {k: jax.lax.with_sharding_constraint(v, sh[k])
+                for k, v in st.items()}
 
     def place_data(self, data):
         """Shard the per-client dataset {"x": (N, n, ...), "y": (N, n)} over

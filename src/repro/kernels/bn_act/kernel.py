@@ -15,8 +15,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import jax.experimental.pallas as pl
-
-from repro.kernels._compat import tpu_compiler_params
+import jax.experimental.pallas.tpu as pltpu
 
 
 def _bn_act_kernel(x_ref, a_ref, b_ref, o_ref, *, relu):
@@ -44,7 +43,7 @@ def bn_act_2d(x, a, b, *, relu=True, block_rows=256, interpret=False):
         ],
         out_specs=pl.BlockSpec((block_rows, Cp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, Cp), x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
         name="sfpl_bn_act",

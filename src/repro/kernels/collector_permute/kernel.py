@@ -16,6 +16,13 @@ Three gathers share the pattern:
     map resolves both levels;
   * ``unbucket_permute_2d``  — its receive-side mirror: gather the flat
     received bucket block into local output order.
+
+Each grid cell moves ONE row, so the kernels see the rows as ``(R, 1, D)``
+with blocks ``(squeezed, 1, block_d)``: the block's last two dims then
+equal the array's unit sublane dim and tile its lanes, which the TPU
+lowering accepts for every dtype. A ``(1, block_d)`` block of an ``(R, D)``
+array breaks its rule that the second-to-last block dim is a multiple of
+8 (32 for int8) or the whole dim.
 """
 from __future__ import annotations
 
@@ -30,28 +37,44 @@ def _permute_kernel(perm_ref, x_ref, o_ref):
     o_ref[...] = x_ref[...]
 
 
+def row_spec(width, index_map):
+    """BlockSpec of one ``width``-lane slice of one row of an ``(R, 1, D)``
+    operand; ``index_map(*grid_idx, *prefetch)`` returns ``(row, lane
+    block)``."""
+    def idx(*args):
+        row, j = index_map(*args)
+        return row, 0, j
+    return pl.BlockSpec((pl.Squeezed(), 1, width), idx)
+
+
+def _row_gather(x, idx, grid, src, dst, rows_out, block_d, name, interpret):
+    """``out[dst(g)] = x[src(g)]`` lane block by lane block over ``grid``
+    (plus a last axis walking the lane blocks); ``x`` is ``(R, D)``."""
+    R, D = x.shape
+    assert D % block_d == 0, (D, block_d)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=grid + (D // block_d,),
+        in_specs=[row_spec(block_d, src)],
+        out_specs=row_spec(block_d, dst),
+    )
+    out = pl.pallas_call(
+        _permute_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((rows_out, 1, D), x.dtype),
+        interpret=interpret,
+        name=name,
+    )(idx.astype(jnp.int32), x.reshape(R, 1, D))
+    return out.reshape(rows_out, D)
+
+
 def collector_permute_2d(x, perm, *, block_d=512, interpret=False):
     """x: (R, D) pooled smashed data (row-major, one row per sample);
     perm: (R,) int32 destination->source map. Returns x[perm]."""
-    R, D = x.shape
-    assert D % block_d == 0, (D, block_d)
-    grid = (R, D // block_d)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_d), lambda i, j, perm: (perm[i], j)),
-        ],
-        out_specs=pl.BlockSpec((1, block_d), lambda i, j, perm: (i, j)),
-    )
-    return pl.pallas_call(
-        _permute_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R, D), x.dtype),
-        interpret=interpret,
-        name="sfpl_collector_permute",
-    )(perm.astype(jnp.int32), x)
+    return _row_gather(
+        x, perm, (x.shape[0],),
+        lambda i, j, perm: (perm[i], j), lambda i, j, perm: (i, j),
+        x.shape[0], block_d, "sfpl_collector_permute", interpret)
 
 
 def bucket_permute_2d(x, idx, *, block_d=512, interpret=False):
@@ -65,28 +88,12 @@ def bucket_permute_2d(x, idx, *, block_d=512, interpret=False):
     then slots, and the prefetched index map resolves both levels to the
     source tile, so rows stream HBM->HBM without an intermediate
     sorted/stacked copy."""
-    R, D = x.shape
     S, cap = idx.shape
-    assert D % block_d == 0, (D, block_d)
-    grid = (S, cap, D // block_d)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_d),
-                         lambda s, r, j, idx: (idx[s, r], j)),
-        ],
-        out_specs=pl.BlockSpec((1, block_d),
-                               lambda s, r, j, idx: (s * cap + r, j)),
-    )
-    return pl.pallas_call(
-        _permute_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S * cap, D), x.dtype),
-        interpret=interpret,
-        name="sfpl_bucket_permute",
-    )(idx.astype(jnp.int32), x)
+    return _row_gather(
+        x, idx, (S, cap),
+        lambda s, r, j, idx: (idx[s, r], j),
+        lambda s, r, j, idx: (s * cap + r, j),
+        S * cap, block_d, "sfpl_bucket_permute", interpret)
 
 
 def unbucket_permute_2d(x, idx, *, block_d=512, interpret=False):
@@ -97,23 +104,8 @@ def unbucket_permute_2d(x, idx, *, block_d=512, interpret=False):
     ``recv_idx``: local output row -> flat (source shard, slot). Returns
     (B, D) with ``out[i] = x[idx[i]]`` — the shuffled output slab, again
     one DMA per tile with no scatter."""
-    R, D = x.shape
     (B,) = idx.shape
-    assert D % block_d == 0, (D, block_d)
-    grid = (B, D // block_d)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_d), lambda i, j, idx: (idx[i], j)),
-        ],
-        out_specs=pl.BlockSpec((1, block_d), lambda i, j, idx: (i, j)),
-    )
-    return pl.pallas_call(
-        _permute_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, D), x.dtype),
-        interpret=interpret,
-        name="sfpl_unbucket_permute",
-    )(idx.astype(jnp.int32), x)
+    return _row_gather(
+        x, idx, (B,),
+        lambda i, j, idx: (idx[i], j), lambda i, j, idx: (i, j),
+        B, block_d, "sfpl_unbucket_permute", interpret)

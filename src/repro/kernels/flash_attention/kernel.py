@@ -17,7 +17,6 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
 
 NEG_INF = -2.3819763e38
 
@@ -114,7 +113,7 @@ def flash_attention_bhsd(q, k, v, *, scale, causal=True, window=None,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -14,11 +14,13 @@ kernels makes the wire conversion free of extra memory traffic:
     flat received block into output order while multiplying each row by
     its (prefetched-index-selected) scale back into the compute dtype.
 
-Both kernels take ONE ROW per grid cell (block ``(1, Dp)``): the amax
-reduction needs the whole row in VMEM, so the feature dim is not tiled.
-The collector's smashed rows are a few hundred lanes after padding —
-far under VMEM pressure; reshape upstream if a future cut layer breaks
-that assumption.
+Both kernels take ONE WHOLE ROW per grid cell: the amax reduction needs
+the whole row in VMEM, so the feature dim is not tiled. Rows travel as
+``(R, 1, Dp)`` and the per-row scales as ``(R, 1, 1)``, with blocks
+``(squeezed, 1, Dp)`` and ``(squeezed, 1, 1)`` — the layout the TPU
+lowering accepts for one-row blocks (``collector_permute.kernel``). A
+32x32x16 smashed row is 64 KiB in f32, far under VMEM pressure; reshape
+upstream if a future cut layer breaks that assumption.
 """
 from __future__ import annotations
 
@@ -28,6 +30,8 @@ import jax
 import jax.numpy as jnp
 import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
+
+from repro.kernels.collector_permute.kernel import row_spec
 
 
 def _quant_kernel(qmax, round_to_int, idx_ref, x_ref, q_ref, s_ref):
@@ -44,6 +48,12 @@ def _quant_kernel(qmax, round_to_int, idx_ref, x_ref, q_ref, s_ref):
                           jnp.float32)
 
 
+def _one_row(index_map):
+    """``row_spec`` over the whole lane dim: ``index_map`` gives the
+    row, the lane block is always 0."""
+    return lambda *a: (index_map(*a), 0)
+
+
 def quant_bucket_permute_2d(x, idx, wire_dtype, qmax, *, interpret=False):
     """Fused quantize + send-side bucket gather.
 
@@ -56,34 +66,31 @@ def quant_bucket_permute_2d(x, idx, wire_dtype, qmax, *, interpret=False):
     cannot perturb the amax."""
     R, D = x.shape
     S, cap = idx.shape
-    grid = (S, cap)
+    src = _one_row(lambda s, r, idx: idx[s, r])
+    dst = _one_row(lambda s, r, idx: s * cap + r)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, D), lambda s, r, idx: (idx[s, r], 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, D), lambda s, r, idx: (s * cap + r, 0)),
-            pl.BlockSpec((1, 1), lambda s, r, idx: (s * cap + r, 0)),
-        ],
+        grid=(S, cap),
+        in_specs=[row_spec(D, src)],
+        out_specs=[row_spec(D, dst), row_spec(1, dst)],
     )
     round_to_int = jnp.issubdtype(jnp.dtype(wire_dtype), jnp.integer)
-    return pl.pallas_call(
+    q, scales = pl.pallas_call(
         functools.partial(_quant_kernel, float(qmax), round_to_int),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((S * cap, D), wire_dtype),
-                   jax.ShapeDtypeStruct((S * cap, 1), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((S * cap, 1, D), wire_dtype),
+                   jax.ShapeDtypeStruct((S * cap, 1, 1), jnp.float32)],
         interpret=interpret,
         name="sfpl_quant_bucket_permute",
-    )(idx.astype(jnp.int32), x)
+    )(idx.astype(jnp.int32), x.reshape(R, 1, D))
+    return q.reshape(S * cap, D), scales.reshape(S * cap, 1)
 
 
 def _dequant_kernel(idx_ref, s_ref, x_ref, o_ref):
     del idx_ref
     o_ref[...] = (x_ref[...].astype(jnp.float32)
-                  * s_ref[0, 0]).astype(o_ref.dtype)
+                  * s_ref[...]).astype(o_ref.dtype)
 
 
 def dequant_unbucket_permute_2d(q, scales, idx, out_dtype, *,
@@ -99,21 +106,19 @@ def dequant_unbucket_permute_2d(q, scales, idx, out_dtype, *,
     same prefetched index map as the row gather."""
     R, D = q.shape
     (B,) = idx.shape
-    grid = (B,)
+    src = _one_row(lambda i, idx: idx[i])
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, idx: (idx[i], 0)),
-            pl.BlockSpec((1, D), lambda i, idx: (idx[i], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, D), lambda i, idx: (i, 0)),
+        grid=(B,),
+        in_specs=[row_spec(1, src), row_spec(D, src)],
+        out_specs=row_spec(D, _one_row(lambda i, idx: i)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _dequant_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, D), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((B, 1, D), out_dtype),
         interpret=interpret,
         name="sfpl_dequant_unbucket_permute",
-    )(idx.astype(jnp.int32), scales, q)
+    )(idx.astype(jnp.int32), scales.reshape(R, 1, 1), q.reshape(R, 1, D))
+    return out.reshape(B, D)

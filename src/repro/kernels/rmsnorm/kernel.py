@@ -13,8 +13,6 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
-
 
 def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps, scale_offset, d_real):
     x = x_ref[...].astype(jnp.float32)            # (br, dp)
@@ -44,7 +42,7 @@ def rmsnorm_2d(x, scale, *, eps=1e-6, scale_offset=0.0, block_rows=256,
         ],
         out_specs=pl.BlockSpec((block_rows, Dp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, Dp), x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
         name="sfpl_rmsnorm",

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels._compat import tpu_compiler_params
-
 
 def _slstm_kernel(xi_ref, xf_ref, xz_ref, xo_ref, r_ref, o_ref,
                   c_ref, n_ref, m_ref, h_ref, *, ts, H, Dh):
@@ -94,7 +92,7 @@ def slstm_scan_pallas(pre_i, pre_f, pre_z, pre_o, R, *, block_b=8,
         out_shape=jax.ShapeDtypeStruct((B, S, HD), pre_i.dtype),
         scratch_shapes=[pltpu.VMEM((block_b, HD), jnp.float32)
                         for _ in range(4)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="sfpl_slstm_scan",

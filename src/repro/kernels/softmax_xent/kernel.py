@@ -19,8 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import jax.experimental.pallas as pl
-
-from repro.kernels._compat import tpu_compiler_params
+import jax.experimental.pallas.tpu as pltpu
 
 
 def _xent_fwd_kernel(x_ref, l_ref, loss_ref, lse_ref, *, v_real):
@@ -69,7 +68,7 @@ def xent_fwd_2d(x, labels, *, v_real=None, block_rows=256, interpret=False):
             jax.ShapeDtypeStruct((R, 1), jnp.float32),
             jax.ShapeDtypeStruct((R, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
         name="sfpl_xent_fwd",
@@ -95,7 +94,7 @@ def xent_bwd_2d(x, labels, lse, g, *, v_real=None, block_rows=256,
         ],
         out_specs=pl.BlockSpec((block_rows, Vp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, Vp), x.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
         name="sfpl_xent_bwd",

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_arch
 from repro.data.tokens import synthetic_token_stream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.common import softmax_cross_entropy
 
 
@@ -62,80 +63,41 @@ def evaluate_lm(spec, cfg, params, *, batches=8, batch=8, seq=64, seed=0):
 
 def evaluate_paper(*, num_clients=8, epochs=4, batch_size=8, sharded=False,
                    alpha=1.0, pipeline="sync", submesh=None, pods=None,
-                   use_kernel=None, depth=8, width=8, hw=8, lr=0.05,
+                   use_kernel=None, lr=0.05,
                    compute_dtype="float32", wire_dtype=None,
-                   wire_dtype_bwd=None, seed=0):
+                   wire_dtype_bwd=None):
     """Train SFPL and SFLv2 through the unified round engine on the same
-    data, fleet size, and placement; return accuracy under BOTH test
-    protocols (IID and non-IID batches) per scheme, so the head-to-head
-    comparison is not confounded by the evaluation protocol. Each scheme
-    is evaluated with the BN treatment it trained with (SFPL: CMSD,
-    batch statistics; SFLv2: RMSD, aggregated running statistics).
-    ``compute_dtype="bfloat16"`` runs both schemes on the mixed-precision
-    ``ComputePolicy`` path (f32 master params and BN statistics);
-    ``wire_dtype`` / ``wire_dtype_bwd`` narrow the sharded SFPL
-    exchange's on-wire dtype (``core.wire`` — SFLv2 has no collector
+    data, fleet size, and placement (``train.build_paper``); return
+    accuracy under BOTH test protocols (IID and non-IID batches) per
+    scheme, so the head-to-head comparison is not confounded by the
+    evaluation protocol. Each scheme is evaluated with the BN treatment it
+    trained with (SFPL: CMSD, batch statistics; SFLv2: RMSD, aggregated
+    running statistics). ``compute_dtype="bfloat16"`` runs both schemes on
+    the mixed-precision ``ComputePolicy`` path (f32 master params and BN
+    statistics); ``wire_dtype`` / ``wire_dtype_bwd`` narrow the sharded
+    SFPL exchange's on-wire dtype (``core.wire`` — SFLv2 has no collector
     exchange, so the knob only affects the SFPL side of the
     comparison)."""
-    from repro.core import engine as E
     from repro.core.evaluate import evaluate_split_iid, evaluate_split_noniid
-    from repro.data import make_synthetic_cifar, partition_positive_labels
-    from repro.launch.train import make_compute_policy
-    from repro.models import resnet as R
-    from repro.optim import sgd_momentum
-
-    cfg = R.ResNetConfig(depth=depth, num_classes=num_clients, width=width)
-    key = jax.random.PRNGKey(seed)
-    tx, ty, ex, ey = make_synthetic_cifar(
-        key, num_classes=num_clients, train_per_class=4 * batch_size,
-        test_per_class=2 * batch_size, hw=hw)
-    data = partition_positive_labels(tx, ty, num_clients)
-    split = E.make_resnet_split(cfg, policy=make_compute_policy(
-        compute_dtype, use_kernel, wire_dtype, wire_dtype_bwd))
-    opt = sgd_momentum(lr, momentum=0.9, weight_decay=5e-4)
+    from repro.launch.train import build_paper
 
     def run(scheme):
-        st = E.init_dcml_state(jax.random.PRNGKey(seed),
-                               lambda k: R.init(k, cfg), num_clients,
-                               opt, opt)
-        if sharded:
-            from repro.core import engine_dist as ED
-            shards = ED.fit_shards(num_clients, batch_size, scheme=scheme,
-                                   alpha=alpha,
-                                   collector_pipeline=pipeline,
-                                   collector_submesh=submesh, pods=pods)
-            mesh = ED.make_data_mesh(shards, pods=pods)
-            if scheme == "sfpl":
-                st = ED.shard_dcml_state(st, mesh)
-                epoch = ED.make_sfpl_epoch_sharded(
-                    split, opt, opt, ED.shard_client_data(data, mesh),
-                    mesh=mesh, num_clients=num_clients,
-                    batch_size=batch_size, alpha=alpha,
-                    collector_pipeline=pipeline,
-                    collector_submesh=submesh, use_kernel=use_kernel,
-                    wire_dtype=wire_dtype, wire_dtype_bwd=wire_dtype_bwd)
-            else:
-                epoch = ED.make_sflv2_epoch_sharded(
-                    split, opt, opt, data, mesh=mesh,
-                    num_clients=num_clients, batch_size=batch_size)
-        elif scheme == "sfpl":
-            epoch = jax.jit(lambda k, s: E.sfpl_epoch(
-                k, s, data, split, opt, opt, num_clients=num_clients,
-                batch_size=batch_size, alpha=alpha))
-        else:
-            epoch = jax.jit(lambda k, s: E.sflv2_epoch(
-                k, s, data, split, opt, opt, num_clients=num_clients,
-                batch_size=batch_size))
-        k = jax.random.PRNGKey(seed + 1)
+        r = build_paper(num_clients=num_clients, batch_size=batch_size,
+                        sharded=sharded, use_kernel=use_kernel,
+                        lr=lr, scheme=scheme, alpha=alpha,
+                        pipeline=pipeline, submesh=submesh, pods=pods,
+                        compute_dtype=compute_dtype, wire_dtype=wire_dtype,
+                        wire_dtype_bwd=wire_dtype_bwd)
+        st, k = r.st, r.key
         for _ in range(epochs):
             k, ke = jax.random.split(k)
-            st, _ = epoch(ke, st)
+            st, _ = r.epoch(ke, st, r.data)
         rmsd = scheme == "sflv2"
+        args = (st, r.split, r.test_x, r.test_y, num_clients)
         return {
-            "iid": evaluate_split_iid(st, split, ex, ey, num_clients,
-                                      rmsd=rmsd, batch=2 * batch_size),
-            "noniid": evaluate_split_noniid(st, split, ex, ey,
-                                            num_clients, rmsd=rmsd,
+            "iid": evaluate_split_iid(*args, rmsd=rmsd,
+                                      batch=2 * batch_size),
+            "noniid": evaluate_split_noniid(*args, rmsd=rmsd,
                                             batch=2 * batch_size),
         }
 
@@ -188,15 +150,8 @@ def main():
                     choices=WIRE_DTYPE_NAMES,
                     help="sharded SFPL: wire dtype of the routed-back "
                          "gradient rows (default: exact)")
-    ap.add_argument("--compilation-cache-dir", dest="compilation_cache_dir",
-                    default=None,
-                    help="persist XLA compilations to this directory "
-                         "(jax_compilation_cache_dir) so repeat launches "
-                         "skip recompiles")
     args = ap.parse_args()
-    if args.compilation_cache_dir:
-        jax.config.update("jax_compilation_cache_dir",
-                          args.compilation_cache_dir)
+    enable_compile_cache()
     if args.paper:
         rep = evaluate_paper(num_clients=args.clients, epochs=args.epochs,
                              sharded=args.sharded, alpha=args.alpha,

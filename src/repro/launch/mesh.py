@@ -2,7 +2,7 @@
 importing this module never touches jax device state)."""
 from __future__ import annotations
 
-import jax
+from repro.core.engine_dist import make_auto_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -17,9 +17,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     here."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Single-device mesh for smoke tests / examples on CPU."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_auto_mesh((1, 1), ("data", "model"))

@@ -28,7 +28,7 @@ Usage:
   PYTHONPATH=src python -m repro.launch.train --paper --sharded \
       --clients 8 --epochs 4 [--scheme sflv2] [--alpha 0.5] \
       [--collector uniform] [--pipeline double_buffered] [--submesh] \
-      [--use-kernel] [--wire-dtype int8] [--compilation-cache-dir .xla] \
+      [--use-kernel] [--wire-dtype int8] [--model resnet32] \
       [--ckpt state.npz --ckpt-every 1] [--resume state.npz] \
       [--drop-rate 0.2 --straggler-rate 0.1 --straggler-timeout 0.5]
 """
@@ -36,12 +36,14 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs import get_arch
 from repro.data.tokens import synthetic_token_stream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import make_train_step
 from repro.optim import sgd_momentum, adamw, cosine_lr
 from repro.checkpoint import save_checkpoint
@@ -107,66 +109,51 @@ def make_compute_policy(compute_dtype, use_kernel=None, wire_dtype=None,
     from repro.models.common import ComputePolicy
     return ComputePolicy(compute_dtype=compute_dtype or "float32",
                          use_fused_kernels=use_kernel,
-                         kernel_interpret=jax.default_backend() != "tpu",
                          wire_dtype=wire, wire_dtype_bwd=wire_bwd)
 
 
-def train_paper(*, num_clients=8, epochs=4, batch_size=8, sharded=False,
-                use_kernel=None, depth=8, width=8, hw=8, lr=0.05,
-                scheme="sfpl", alpha=1.0, collector="balanced",
-                pipeline="sync", submesh=None, pods=None,
-                compute_dtype="float32", wire_dtype=None,
-                wire_dtype_bwd=None, log_every=1,
-                ckpt=None, ckpt_every=0, resume=None,
-                straggler_timeout=None, drop_rate=0.0, straggler_rate=0.0,
-                straggler_delay=1.0, fault_seed=0):
-    """DCML rounds on synthetic CIFAR, one client per class (only positive
-    labels). ``scheme`` picks SFPL (Algorithm 1 + 2) or the SFLv2 baseline;
-    ``sharded`` runs the same round body on a mesh over all visible devices
-    (SFPL: clients + pooled batch sharded, collector as all_to_all in
-    ``collector`` mode with flush threshold ``alpha``; SFLv2: the server
-    stream sharded over the batch axis, visitation order preserved).
-    ``compute_dtype="bfloat16"`` switches the split model onto the
-    mixed-precision ``ComputePolicy`` path: f32 master params and BN
-    stats, bf16 compute and smashed-data exchange, fused Pallas epilogues
-    on TPU. ``wire_dtype`` (sharded SFPL) narrows the exchange payload
-    independently of the compute dtype — int8/fp8 wires quantize per row
-    right before each collective (``core.wire``); ``wire_dtype_bwd``
-    does the same for the routed-back gradient rows. ``pods`` splits the sharded SFPL mesh into the 2-D
-    ``("pod", "data")`` multi-host topology (one pod per host process
-    under ``launch.multihost.initialize``; also works single-process for
-    schedule parity testing).
+class PaperRun(NamedTuple):
+    """A placed paper-mode run: ``epoch(key, st, data[, participation])
+    -> (st, losses)`` is the jitted round (state donated), ``st``/``data``
+    its placed arguments, ``key`` the epoch-key stream, ``start_epoch`` the
+    epoch to resume from, and ``split``/``test_x``/``test_y`` what the
+    final evaluation needs."""
+    epoch: Callable
+    st: dict
+    data: dict
+    key: jax.Array
+    start_epoch: int
+    split: object
+    test_x: jax.Array
+    test_y: jax.Array
 
-    Fault tolerance (SFPL only): ``drop_rate`` / ``straggler_rate`` drive
-    a deterministic :class:`~repro.core.faults.FaultPlan` whose per-epoch
-    participation mask is threaded into the round — absent clients'
-    activations are masked out of pooling/BN/loss and their local state is
-    frozen for the epoch. ``straggler_timeout=None`` WAITS for stragglers
-    (the host stalls); a finite timeout DROPS-AND-MASKS them. A draw that
-    would empty a flush group has its lowest-index client revived (logged).
-    ``ckpt`` + ``ckpt_every`` snapshot the full training state (params,
-    optimizer, BN stats, PRNG key, epoch) every N epochs; ``resume``
-    restores such a snapshot and continues bit-compatibly — on a sharded
-    mesh only process 0 writes, but every process calls the (collective)
-    save."""
+
+def paper_model(model, num_clients):
+    """(ResNetConfig, input side) for paper mode: ``model`` names one of
+    the paper's ResNets (``configs.resnet_cifar.MODELS``, width 16 on
+    32x32x3 inputs); ``None`` keeps the small CPU-sized default."""
+    from repro.models import resnet as R
+    if model is None:
+        return R.ResNetConfig(depth=8, num_classes=num_clients, width=8), 8
+    from repro.configs.resnet_cifar import MODELS
+    return MODELS[model](num_classes=num_clients), 32
+
+
+def build_paper(*, num_clients=8, batch_size=8, model=None, sharded=False,
+                use_kernel=None, lr=0.05, scheme="sfpl", alpha=1.0,
+                collector="balanced", pipeline="sync", submesh=None,
+                pods=None, compute_dtype="float32", wire_dtype=None,
+                wire_dtype_bwd=None, resume=None):
+    """Data, split model, state and the jitted epoch of one paper-mode run
+    (see ``train_paper`` for the knobs), placed on one device or on the
+    collector mesh. Returns a :class:`PaperRun`."""
     from repro.core import engine as E
-    from repro.core.evaluate import evaluate_split_noniid
-    from repro.core.faults import FaultPlan, ensure_group_survivor
     from repro.data import make_synthetic_cifar, partition_positive_labels
     from repro.models import resnet as R
     from repro.optim import sgd_momentum
     from repro import checkpoint as CK
 
-    plan = None
-    if drop_rate or straggler_rate:
-        if scheme != "sfpl":
-            raise ValueError("elastic participation (drop/straggler rates) "
-                             "requires --scheme sfpl")
-        plan = FaultPlan(num_clients, seed=fault_seed, drop_rate=drop_rate,
-                         straggler_rate=straggler_rate,
-                         straggler_delay=straggler_delay)
-
-    cfg = R.ResNetConfig(depth=depth, num_classes=num_clients, width=width)
+    cfg, hw = paper_model(model, num_clients)
     key = jax.random.PRNGKey(0)
     tx, ty, ex, ey = make_synthetic_cifar(
         key, num_classes=num_clients, train_per_class=4 * batch_size,
@@ -184,6 +171,7 @@ def train_paper(*, num_clients=8, epochs=4, batch_size=8, sharded=False,
         st, key, start_ep = CK.restore_train_state(resume, st, key_ref=key)
         print(f"resumed from {resume} at epoch {start_ep}")
 
+    common = dict(num_clients=num_clients, batch_size=batch_size)
     if sharded:
         from repro.core import engine_dist as ED
         n_dev = len(jax.devices())
@@ -192,9 +180,8 @@ def train_paper(*, num_clients=8, epochs=4, batch_size=8, sharded=False,
             mesh = ED.make_data_mesh(shards)
             print(f"sharded SFLv2: server stream over a {shards}-way mesh "
                   f"({n_dev} device(s)), sequential visitation preserved")
-            epoch = ED.make_sflv2_epoch_sharded(
-                split, opt, opt, data, mesh=mesh, num_clients=num_clients,
-                batch_size=batch_size)
+            fn = lambda k, s, d: ED.sflv2_epoch_sharded(
+                k, s, d, split, opt, opt, mesh=mesh, **common)
         else:
             shards = ED.fit_shards(num_clients, batch_size, alpha=alpha,
                                    collector_mode=collector,
@@ -208,35 +195,92 @@ def train_paper(*, num_clients=8, epochs=4, batch_size=8, sharded=False,
                   f"pipeline={pipeline}, submesh={submesh}, pods={pods}, "
                   f"use_kernel={use_kernel}, compute_dtype={compute_dtype}, "
                   f"wire_dtype={wire_dtype}, wire_dtype_bwd={wire_dtype_bwd}")
-            data_dev = ED.shard_client_data(data, mesh)
+            data = ED.shard_client_data(data, mesh)
             st = ED.shard_dcml_state(st, mesh)
-            epoch = ED.make_sfpl_epoch_sharded(
-                split, opt, opt, data_dev, mesh=mesh,
-                num_clients=num_clients, batch_size=batch_size,
-                use_kernel=use_kernel, alpha=alpha,
-                collector_mode=collector, collector_pipeline=pipeline,
-                collector_submesh=submesh, wire_dtype=wire_dtype,
-                wire_dtype_bwd=wire_dtype_bwd)
+            fn = lambda k, s, d, m=None: ED.sfpl_epoch_sharded(
+                k, s, d, split, opt, opt, mesh=mesh, use_kernel=use_kernel,
+                alpha=alpha, collector_mode=collector,
+                collector_pipeline=pipeline, collector_submesh=submesh,
+                wire_dtype=wire_dtype, wire_dtype_bwd=wire_dtype_bwd,
+                participation=m, **common)
     elif scheme == "sflv2":
-        epoch = jax.jit(lambda k, s: E.sflv2_epoch(
-            k, s, data, split, opt, opt, num_clients=num_clients,
-            batch_size=batch_size))
+        fn = lambda k, s, d: E.sflv2_epoch(k, s, d, split, opt, opt,
+                                           **common)
     else:
-        dense = jax.jit(lambda k, s: E.sfpl_epoch(
-            k, s, data, split, opt, opt, num_clients=num_clients,
-            batch_size=batch_size, alpha=alpha))
-        masked = jax.jit(lambda k, s, m: E.sfpl_epoch(
-            k, s, data, split, opt, opt, num_clients=num_clients,
-            batch_size=batch_size, alpha=alpha, participation=m))
+        fn = lambda k, s, d, m=None: E.sfpl_epoch(
+            k, s, d, split, opt, opt, alpha=alpha, participation=m,
+            **common)
+    return PaperRun(jax.jit(fn, donate_argnums=(1,)), st, data, key,
+                    start_ep, split, ex, ey)
 
-        def epoch(k, s, participation=None):
-            if participation is None:
-                return dense(k, s)
-            return masked(k, s, jnp.asarray(participation))
+
+def train_paper(*, num_clients=8, epochs=4, batch_size=8, sharded=False,
+                use_kernel=None, model=None, lr=0.05,
+                scheme="sfpl", alpha=1.0, collector="balanced",
+                pipeline="sync", submesh=None, pods=None,
+                compute_dtype="float32", wire_dtype=None,
+                wire_dtype_bwd=None, log_every=1,
+                ckpt=None, ckpt_every=0, resume=None,
+                straggler_timeout=None, drop_rate=0.0, straggler_rate=0.0,
+                straggler_delay=1.0, fault_seed=0):
+    """DCML rounds on synthetic CIFAR, one client per class (only positive
+    labels). ``model`` picks one of the paper's ResNets (``"resnet8"``,
+    ``"resnet32"``, ``"resnet56"``: width 16, 32x32x3 inputs); ``None``
+    runs the small CPU-sized default (depth 8, width 8, 8x8 inputs).
+    ``scheme`` picks SFPL (Algorithm 1 + 2) or the SFLv2 baseline;
+    ``sharded`` runs the same round body on a mesh over all visible devices
+    (SFPL: clients + pooled batch sharded, collector as all_to_all in
+    ``collector`` mode with flush threshold ``alpha``; SFLv2: the server
+    stream sharded over the batch axis, visitation order preserved).
+    ``compute_dtype="bfloat16"`` switches the split model onto the
+    mixed-precision ``ComputePolicy`` path: f32 master params and BN
+    stats, bf16 compute and smashed-data exchange, fused Pallas epilogues
+    on TPU. ``wire_dtype`` (sharded SFPL) narrows the exchange payload
+    independently of the compute dtype — int8/fp8 wires quantize per row
+    right before each collective (``core.wire``); ``wire_dtype_bwd``
+    does the same for the routed-back gradient rows. ``pods`` splits the
+    sharded SFPL mesh into the 2-D ``("pod", "data")`` multi-host
+    topology (one pod per host process under
+    ``launch.multihost.initialize``; also works single-process for
+    schedule parity testing).
+
+    Fault tolerance (SFPL only): ``drop_rate`` / ``straggler_rate`` drive
+    a deterministic :class:`~repro.core.faults.FaultPlan` whose per-epoch
+    participation mask is threaded into the round — absent clients'
+    activations are masked out of pooling/BN/loss and their local state is
+    frozen for the epoch. ``straggler_timeout=None`` WAITS for stragglers
+    (the host stalls); a finite timeout DROPS-AND-MASKS them. A draw that
+    would empty a flush group has its lowest-index client revived (logged).
+    ``ckpt`` + ``ckpt_every`` snapshot the full training state (params,
+    optimizer, BN stats, PRNG key, epoch) every N epochs; ``resume``
+    restores such a snapshot and continues bit-compatibly — on a sharded
+    mesh only process 0 writes, but every process calls the (collective)
+    save."""
+    from repro.core.evaluate import evaluate_split_noniid
+    from repro.core.faults import FaultPlan, ensure_group_survivor
+    from repro import checkpoint as CK
+
+    plan = None
+    if drop_rate or straggler_rate:
+        if scheme != "sfpl":
+            raise ValueError("elastic participation (drop/straggler rates) "
+                             "requires --scheme sfpl")
+        plan = FaultPlan(num_clients, seed=fault_seed, drop_rate=drop_rate,
+                         straggler_rate=straggler_rate,
+                         straggler_delay=straggler_delay)
+
+    run = build_paper(num_clients=num_clients, batch_size=batch_size,
+                      model=model, sharded=sharded, use_kernel=use_kernel,
+                      lr=lr, scheme=scheme, alpha=alpha,
+                      collector=collector, pipeline=pipeline,
+                      submesh=submesh, pods=pods,
+                      compute_dtype=compute_dtype, wire_dtype=wire_dtype,
+                      wire_dtype_bwd=wire_dtype_bwd, resume=resume)
+    st, key = run.st, run.key
 
     t0 = time.time()
     mean_losses = []
-    for ep in range(start_ep, epochs):
+    for ep in range(run.start_epoch, epochs):
         mask = None
         if plan is not None:
             mask, wait = plan.participation(
@@ -252,9 +296,9 @@ def train_paper(*, num_clients=8, epochs=4, batch_size=8, sharded=False,
                 time.sleep(wait)
         key, ke = jax.random.split(key)
         if mask is None:
-            st, losses = epoch(ke, st)
+            st, losses = run.epoch(ke, st, run.data)
         else:
-            st, losses = epoch(ke, st, participation=mask)
+            st, losses = run.epoch(ke, st, run.data, jnp.asarray(mask))
         mean_losses.append(float(losses.mean()))
         if ep % log_every == 0 or ep == epochs - 1:
             print(f"epoch {ep:3d} loss {mean_losses[-1]:.4f} "
@@ -265,7 +309,8 @@ def train_paper(*, num_clients=8, epochs=4, batch_size=8, sharded=False,
     if ckpt:
         CK.save_train_state(ckpt, st, key=key, epoch=epochs)
         print(f"saved final training state to {ckpt}")
-    rep = evaluate_split_noniid(st, split, ex, ey, num_clients, rmsd=False,
+    rep = evaluate_split_noniid(st, run.split, run.test_x, run.test_y,
+                                num_clients, rmsd=False,
                                 batch=2 * batch_size)
     print(f"non-IID accuracy {rep['accuracy']:.1f}% "
           f"(chance {100.0 / num_clients:.1f}%)")
@@ -340,11 +385,11 @@ def main():
                     help="sharded SFPL: wire dtype of the routed-back "
                          "gradient rows (default: exact — the backward "
                          "leg is the more quantization-sensitive one)")
-    ap.add_argument("--compilation-cache-dir", dest="compilation_cache_dir",
-                    default=None,
-                    help="persist XLA compilations to this directory "
-                         "(jax_compilation_cache_dir) so repeat launches "
-                         "skip recompiles")
+    ap.add_argument("--model", default=None,
+                    choices=("resnet8", "resnet32", "resnet56"),
+                    help="paper mode: one of the paper's ResNets at "
+                         "published width (16, 32x32x3 inputs); default: "
+                         "a small CPU-sized ResNet-8 (width 8, 8x8 inputs)")
     ap.add_argument("--clients", type=int, default=8)
     ap.add_argument("--epochs", type=int, default=4)
     ap.add_argument("--ckpt-every", dest="ckpt_every", type=int, default=0,
@@ -375,13 +420,11 @@ def main():
                     help="FaultPlan seed — the whole fault schedule is a "
                          "pure function of (seed, epoch)")
     args = ap.parse_args()
-    if args.compilation_cache_dir:
-        jax.config.update("jax_compilation_cache_dir",
-                          args.compilation_cache_dir)
+    enable_compile_cache()
     if args.paper:
         losses = train_paper(num_clients=args.clients, epochs=args.epochs,
                              batch_size=args.batch, sharded=args.sharded,
-                             use_kernel=args.use_kernel,
+                             use_kernel=args.use_kernel, model=args.model,
                              scheme=args.scheme, alpha=args.alpha,
                              collector=args.collector,
                              pipeline=args.pipeline, submesh=args.submesh,

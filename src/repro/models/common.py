@@ -42,12 +42,11 @@ class ComputePolicy:
 
     ``use_fused_kernels`` follows the repo-wide ``None`` = auto-on-TPU
     convention and gates the fused Pallas ``bn_act`` / ``softmax_xent``
-    epilogues; ``kernel_interpret`` forces Pallas interpret mode so the
-    fused path can run (slowly) in CPU CI.
+    epilogues; forced on off-TPU they run in Pallas interpret mode
+    (``kernels.platform.interpret``).
     """
     compute_dtype: str = "float32"
     use_fused_kernels: Optional[bool] = None
-    kernel_interpret: bool = False
     wire_dtype: Optional[str] = None
     wire_dtype_bwd: Optional[str] = None
 
@@ -63,7 +62,7 @@ class ComputePolicy:
         return x.astype(self.cdtype()) if self.mixed else x
 
     def fused(self) -> bool:
-        from repro.kernels._compat import auto_use_kernel
+        from repro.kernels.platform import auto_use_kernel
         return auto_use_kernel(self.use_fused_kernels)
 
 

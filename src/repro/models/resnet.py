@@ -14,6 +14,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.platform import interpret
 from repro.nn.conv import conv2d_init, conv2d_apply
 from repro.nn.linear import dense_init, dense_apply
 from repro.nn.norm import (batchnorm_init, batchnorm_apply,
@@ -97,7 +98,7 @@ def _bn(p, s, x, *, training, rmsd, policy=None, relu=False, valid=None):
     return batchnorm_act_apply(p, s, x, training=training, relu=relu,
                                use_running_stats=rmsd,
                                use_kernel=policy.fused(),
-                               interpret=policy.kernel_interpret,
+                               interpret=interpret(),
                                valid=valid)
 
 

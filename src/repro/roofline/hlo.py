@@ -85,3 +85,22 @@ def collective_bytes_from_text(hlo_text):
         d["bytes"] += b
         d["traffic_bytes"] += int(b * _COLL_FACTOR[op])
     return out
+
+
+# e.g.:  %sfpl_bucket_permute.1 = f32[160,1,16384]{...} custom-call(...),
+#        custom_call_target="tpu_custom_call", ...
+_KERNEL_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w\-]+?)(?:\.\d+)?\s*=.*"
+                        r'custom_call_target="tpu_custom_call"')
+
+
+def pallas_kernel_counts(hlo_text):
+    """{kernel name: number of ``tpu_custom_call`` instructions} in a
+    compiled TPU module's text. A Pallas kernel's instruction is named
+    after its ``pallas_call(name=...)``, so a kernel that silently fell
+    back to its reference path is simply absent."""
+    counts = {}
+    for line in hlo_text.splitlines():
+        m = _KERNEL_RE.match(line)
+        if m:
+            counts[m.group(1)] = counts.get(m.group(1), 0) + 1
+    return counts

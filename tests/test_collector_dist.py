@@ -19,7 +19,8 @@ from repro.core.collector_dist import (
     max_pair_load, pair_capacity)
 from repro.core.collector import inverse_permutation
 
-mesh = jax.make_mesh((8,), ("data",))
+from repro.core.engine_dist import make_data_mesh
+mesh = make_data_mesh(8)
 N, D = 64, 5
 key = jax.random.PRNGKey(0)
 x = jax.random.normal(key, (N, D))

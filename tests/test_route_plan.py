@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from repro.core import engine_dist as ED
 
 WORKER_PLAN = r"""
 import os
@@ -26,7 +27,8 @@ from repro.core.collector_dist import (
     build_route_plans, exact_pair_cap, make_balanced_perm, pair_capacity,
     plan_shuffle, shuffle_shard_map)
 
-mesh = jax.make_mesh((8,), ("data",))
+from repro.core.engine_dist import make_data_mesh
+mesh = make_data_mesh(8)
 N, D = 64, 5
 key = jax.random.PRNGKey(0)
 x = jax.random.normal(key, (N, D))
@@ -196,7 +198,7 @@ def test_plan_exchange_is_one_collective_per_direction():
     the plan build contains a single sort."""
     from repro.core.collector_dist import (build_route_plans,
                                            exact_pair_cap, plan_shuffle)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = ED.make_data_mesh(1)
     n = 16
     x = jnp.zeros((n, 3))
     perm = jax.random.permutation(jax.random.PRNGKey(0), n)
@@ -238,7 +240,7 @@ def test_dense_plan_allocates_no_pos_valid_buffers():
         assert len(leaves) == 2, leaves        # send_idx + recv_idx only
         # and the plan reproduces the oracle on one shard-slab layout
         x = jax.random.normal(jax.random.PRNGKey(1), (n, 2))
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = ED.make_data_mesh(1)
     from repro.core.collector_dist import plan_shuffle
     plans1 = build_route_plans(perm, 1, cap=exact_pair_cap(n, 1),
                                may_drop=False)
@@ -272,7 +274,8 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.core import round as RD
 from repro.core.round import streamed_shuffle
 
-mesh = jax.make_mesh((8,), ("data",))
+from repro.core.engine_dist import make_data_mesh
+mesh = make_data_mesh(8)
 coll = RD.StreamingAllToAll(mesh=mesh, num_clients=8, alpha=0.25,
                             mode="balanced", submesh=True)
 n, d = 64, 3
@@ -300,7 +303,7 @@ assert fwd_jaxpr.count("all_to_all") == groups, fwd_jaxpr
 assert fwd_jaxpr.count("sort[") == 0, fwd_jaxpr
 # zero slack padding at every grouped flush: each collective moves the
 # per-shard (S=2, cap=4, d) bucket — exactly the b-row slab, no b_g + 1
-shapes = re.findall(r"f32\[([\d,]+)\] = all_to_all", fwd_jaxpr)
+shapes = re.findall(r"f32\[([\d,]+)\](?:\{[^}]*\})? = all_to_all", fwd_jaxpr)
 assert len(shapes) == groups, fwd_jaxpr
 for shape in shapes:
     s_, cap_, d_ = map(int, shape.split(","))
@@ -430,7 +433,9 @@ def test_quantized_exchange_is_one_collective_in_wire_dtype():
 
     from repro.core.collector_dist import (build_route_plans,
                                            exact_pair_cap, plan_shuffle)
-    mesh = jax.make_mesh((1,), ("data",))
+    # an all_to_all's (dtype, shape), past any {V:...} varying-axes mark
+    a2a_ops = r"(\w+)\[([\d,]+)\](?:\{[^}]*\})? = all_to_all"
+    mesh = ED.make_data_mesh(1)
     n, d = 16, 3
     x = jnp.zeros((n, d))
     perm = jax.random.permutation(jax.random.PRNGKey(0), n)
@@ -441,7 +446,7 @@ def test_quantized_exchange_is_one_collective_in_wire_dtype():
         v, pl, mesh=mesh, wire_dtype="int8"))(x, plans))
     assert fwd_jaxpr.count("all_to_all") == 1, fwd_jaxpr
     assert fwd_jaxpr.count("sort[") == 0, fwd_jaxpr
-    ops = re.findall(r"(\w+)\[([\d,]+)\] = all_to_all", fwd_jaxpr)
+    ops = re.findall(a2a_ops, fwd_jaxpr)
     assert ops == [("i8", f"1,{n},{d + 4}")], ops
 
     # quantized fwd + quantized bwd: both payloads in the wire dtype
@@ -450,14 +455,14 @@ def test_quantized_exchange_is_one_collective_in_wire_dtype():
                                wire_dtype_bwd="int8").sum())(v))(x, plans))
     assert grad_jaxpr.count("all_to_all") == 2, grad_jaxpr
     assert grad_jaxpr.count("sort[") == 0, grad_jaxpr
-    ops = re.findall(r"(\w+)\[([\d,]+)\] = all_to_all", grad_jaxpr)
+    ops = re.findall(a2a_ops, grad_jaxpr)
     assert ops == [("i8", f"1,{n},{d + 4}")] * 2, ops
 
     # default exact backward: the VJP collective stays f32
     grad_exact = str(jax.make_jaxpr(lambda v, pl: jax.grad(
         lambda u: plan_shuffle(u, pl, mesh=mesh,
                                wire_dtype="int8").sum())(v))(x, plans))
-    ops = re.findall(r"(\w+)\[([\d,]+)\] = all_to_all", grad_exact)
+    ops = re.findall(a2a_ops, grad_exact)
     assert ("f32", f"1,{n},{d}") in ops, ops
 
 
@@ -469,7 +474,8 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.core import round as RD
 from repro.core.round import streamed_shuffle
 
-mesh = jax.make_mesh((8,), ("data",))
+from repro.core.engine_dist import make_data_mesh
+mesh = make_data_mesh(8)
 coll = RD.StreamingAllToAll(mesh=mesh, num_clients=8, alpha=0.25,
                             mode="balanced", submesh=True,
                             wire_dtype="int8", wire_dtype_bwd="int8")
@@ -487,7 +493,7 @@ assert fwd_jaxpr.count("all_to_all") == groups, fwd_jaxpr
 assert fwd_jaxpr.count("sort[") == 0, fwd_jaxpr
 # one collective per flush group, payload IN the wire dtype with the
 # scale lanes packed on: i8 (S=2, cap=4, d+4) — still zero slack rows
-ops = re.findall(r"(\w+)\[([\d,]+)\] = all_to_all", fwd_jaxpr)
+ops = re.findall(r"(\w+)\[([\d,]+)\](?:\{[^}]*\})? = all_to_all", fwd_jaxpr)
 assert len(ops) == groups, fwd_jaxpr
 for dt, shape in ops:
     assert dt == "i8", (dt, shape)
@@ -499,7 +505,7 @@ back_jaxpr = str(jax.make_jaxpr(
     lambda v, pr: coll.route_back(v, pr, n))(x, prep))
 assert back_jaxpr.count("all_to_all") == groups, back_jaxpr
 assert back_jaxpr.count("sort[") == 0, back_jaxpr
-ops = re.findall(r"(\w+)\[([\d,]+)\] = all_to_all", back_jaxpr)
+ops = re.findall(r"(\w+)\[([\d,]+)\](?:\{[^}]*\})? = all_to_all", back_jaxpr)
 assert len(ops) == groups and all(dt == "i8" for dt, _ in ops), ops
 print("submesh-quant-route-back OK")
 """

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from _propshim import given, settings, strategies as st
+from repro.core import engine_dist as ED
 
 WORKER_PARITY = r"""
 import os
@@ -95,7 +96,7 @@ def test_double_buffered_matches_sync(_, tmp_path):
 
 def _one_shard_strategy(num_clients, alpha, mode):
     from repro.core import round as RD
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = ED.make_data_mesh(1)
     return RD.StreamingAllToAll(mesh=mesh, num_clients=num_clients,
                                 alpha=alpha, mode=mode)
 
@@ -133,7 +134,7 @@ def test_issue_complete_composition_matches_shuffle():
     from repro.core.collector_dist import (exchange_complete,
                                            exchange_issue,
                                            shuffle_shard_map)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = ED.make_data_mesh(1)
     n = 24
     key = jax.random.PRNGKey(3)
     x = jax.random.normal(key, (n, 4))

@@ -30,6 +30,7 @@ import jax
 import numpy as np
 import pytest
 from _propshim import given, settings, strategies as st
+from repro.core import engine_dist as ED
 
 WORKER_TEMPLATE = r"""
 import os
@@ -234,7 +235,7 @@ def test_whole_mesh_simulation_matches_jax_oracle():
     mesh available in-process), so the sub-mesh property above is not
     tested against a broken model of the collective."""
     from repro.core.collector_dist import build_route_plans, plan_shuffle
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = ED.make_data_mesh(1)
     n = 12
     rng = np.random.default_rng(5)
     x = rng.standard_normal((n, 4)).astype(np.float32)
@@ -258,7 +259,7 @@ def test_streamed_uniform_slack_cached_per_group_size():
     from repro.core import round as RD
     from repro.core.collector_dist import _uniform_auto_slack_cached
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = ED.make_data_mesh(1)
     coll = RD.StreamingAllToAll(mesh=mesh, num_clients=8, alpha=0.25,
                                 mode="uniform")
     n = 8 * 6
@@ -287,7 +288,7 @@ def test_submesh_knob_validation():
     fallback; the sync pipeline rejects the knob outright."""
     from repro.core import round as RD
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = ED.make_data_mesh(1)
     uni = RD.StreamingAllToAll(mesh=mesh, num_clients=8, alpha=0.25,
                                mode="uniform", submesh=True)
     with pytest.raises(ValueError, match="balanced"):

@@ -189,7 +189,8 @@ def test_state_sharding_kv_fallback_to_slots():
         pytest.skip("host test")
     # use spec computation only via a real 1x1 mesh is trivial; check the
     # logic through the stub-free path with a real mesh of the right names
-    mesh = _jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh()
     sds = {"sub0": {"k": _jax.ShapeDtypeStruct((4, 128, 32768, 8, 128),
                                                jnp.bfloat16)}}
     out = state_shardings(sds, mesh)
