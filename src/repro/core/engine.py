@@ -32,6 +32,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.core import collector as C
 from repro.core import round as RD
 from repro.core.bn_policy import fedavg, aggregate_bn_state
 from repro.core.round import make_client_update  # noqa: F401  (re-export)
@@ -156,6 +157,35 @@ def sflv2_epoch(key, st, data, split: SplitModel, opt_c, opt_s, *,
         key, st, data, split, opt_c, opt_s, num_clients=num_clients,
         batch_size=batch_size, aggregate_bn=aggregate_bn,
         placement=RD.SINGLE)
+
+
+def jit_epoch(epoch, data, *, num_clients, alpha=1.0):
+    """Jitted hot loop around ``epoch(key, st, data[, participation]) ->
+    (st, losses)``: returns ``run(key, st[, participation])`` with ``data``
+    bound and the carried state donated, so the param/opt buffers are
+    reused in place.
+
+    ``data`` rides through the jit boundary as an ARGUMENT, not a closure:
+    multi-host global arrays span non-addressable devices and jax refuses
+    to close over them. ``run.jitted`` is the jitted ``(key, st, data[,
+    participation])`` function itself, for ``.lower()``.
+
+    A ``participation`` mask (``(num_clients,)`` or ``(steps,
+    num_clients)`` bool) is validated eagerly on the host (>= 1 survivor
+    per flush group of ``alpha``) and then rides through the jit boundary
+    as a TRACED argument: every epoch's mask reuses one specialization
+    instead of retracing per draw of a fault schedule. ``None`` and masked
+    epochs are separate specializations (two traces)."""
+    jitted = jax.jit(epoch, donate_argnums=(1,))
+
+    def run(key, st, participation=None):
+        if participation is None:
+            return jitted(key, st, data)
+        mask = C.check_participation(num_clients, participation,
+                                     alpha=alpha)
+        return jitted(key, st, data, jnp.asarray(mask))
+    run.jitted = jitted
+    return run
 
 
 # --------------------------------------------------------------------------

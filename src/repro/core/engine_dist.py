@@ -44,13 +44,13 @@ in place shard-by-shard.
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
 from repro.core import collector as C
 from repro.core import round as RD
 from repro.core.collector_dist import (group_fits_slabs, mesh_axis_size,
                                        submesh_slice_size)
-from repro.core.engine import SplitModel, make_client_update  # noqa: F401
+from repro.core.engine import (SplitModel, jit_epoch,  # noqa: F401
+                               make_client_update)
 from repro.core.wire import resolve_wire_dtype
 
 
@@ -410,37 +410,18 @@ def make_sfpl_epoch_sharded(split: SplitModel, opt_c, opt_s, data, *,
                             mesh, num_clients, batch_size, **kw):
     """Jitted hot loop: ``(key, st[, participation]) -> (st, losses)``
     with the carried state donated, so the sharded param/opt buffers are
-    reused in place.
-
-    ``data`` is bound as a jit ARGUMENT, not a closure: multi-host global
-    arrays span non-addressable devices and jax refuses to close over
-    them, while passing them through the jit boundary is fine.
-
-    The returned callable takes an optional ``participation`` mask
-    (``(num_clients,)`` or ``(steps, num_clients)`` bool) for elastic
-    rounds. It is validated eagerly on the host (>= 1 survivor per flush
-    group — so fully-dropped flush groups, and with them the streamed
-    skip fast path, cannot arise here) and then rides through the jit
-    boundary as a TRACED argument: every epoch's mask reuses one
-    specialization instead of retracing per draw of a fault schedule.
-    ``None`` and masked epochs are separate specializations (two
-    traces)."""
-    alpha = kw.get("alpha", 1.0)
-
+    reused in place (``engine.jit_epoch``: ``data`` is a jit argument, a
+    ``participation`` mask is validated on the host — so fully-dropped
+    flush groups, and with them the streamed skip fast path, cannot arise
+    here — then traced; ``.jitted`` is the ``(key, st, data[,
+    participation])`` function)."""
     def epoch(key, st, data, participation=None):
         return sfpl_epoch_sharded(key, st, data, split, opt_c, opt_s,
                                   mesh=mesh, num_clients=num_clients,
                                   batch_size=batch_size,
                                   participation=participation, **kw)
-    jitted = jax.jit(epoch, donate_argnums=(1,))
-
-    def run(key, st, participation=None):
-        if participation is None:
-            return jitted(key, st, data)
-        mask = C.check_participation(num_clients, participation,
-                                     alpha=alpha)
-        return jitted(key, st, data, jnp.asarray(mask))
-    return run
+    return jit_epoch(epoch, data, num_clients=num_clients,
+                     alpha=kw.get("alpha", 1.0))
 
 
 def sflv2_epoch_sharded(key, st, data, split: SplitModel, opt_c, opt_s, *,
@@ -473,11 +454,10 @@ def sflv2_epoch_sharded(key, st, data, split: SplitModel, opt_c, opt_s, *,
 def make_sflv2_epoch_sharded(split: SplitModel, opt_c, opt_s, data, *,
                              mesh, num_clients, batch_size, **kw):
     """Jitted hot loop: ``(key, st) -> (st, losses)``, state donated;
-    ``data`` rides through the jit boundary as an argument (see
-    ``make_sfpl_epoch_sharded``)."""
+    ``data`` rides through the jit boundary as an argument
+    (``engine.jit_epoch``)."""
     def epoch(key, st, data):
         return sflv2_epoch_sharded(key, st, data, split, opt_c, opt_s,
                                    mesh=mesh, num_clients=num_clients,
                                    batch_size=batch_size, **kw)
-    jitted = jax.jit(epoch, donate_argnums=(1,))
-    return lambda key, st: jitted(key, st, data)
+    return jit_epoch(epoch, data, num_clients=num_clients)

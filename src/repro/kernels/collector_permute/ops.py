@@ -9,8 +9,6 @@ import jax.numpy as jnp
 from repro.kernels.collector_permute.kernel import (
     bucket_permute_2d, collector_permute_2d, unbucket_permute_2d)
 
-MAX_BLOCK_LANES = 65536
-
 
 def _flatten_features(x):
     """(R, ...) -> (R, Dp) with the feature dims flattened and padded to a
@@ -24,10 +22,7 @@ def _flatten_features(x):
     dp = max(128, -(-d // 128) * 128)
     if dp != d:
         x2 = jnp.pad(x2, ((0, 0), (0, dp - d)))
-    # whole rows per grid cell (a 32x32x16 f32 row is 64 KiB of VMEM per
-    # buffer); only rows past MAX_BLOCK_LANES are cut into lane blocks
-    block_d = (dp if dp <= MAX_BLOCK_LANES
-               else 512 if dp % 512 == 0 else 128)
+    block_d = dp if dp <= 512 else 512 if dp % 512 == 0 else 128
     return x2, d, dp, block_d, orig_shape[1:]
 
 

@@ -91,7 +91,7 @@ def evaluate_paper(*, num_clients=8, epochs=4, batch_size=8, sharded=False,
         st, k = r.st, r.key
         for _ in range(epochs):
             k, ke = jax.random.split(k)
-            st, _ = r.epoch(ke, st, r.data)
+            st, _ = r.epoch(ke, st)
         rmsd = scheme == "sflv2"
         args = (st, r.split, r.test_x, r.test_y, num_clients)
         return {

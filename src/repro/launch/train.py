@@ -113,11 +113,13 @@ def make_compute_policy(compute_dtype, use_kernel=None, wire_dtype=None,
 
 
 class PaperRun(NamedTuple):
-    """A placed paper-mode run: ``epoch(key, st, data[, participation])
-    -> (st, losses)`` is the jitted round (state donated), ``st``/``data``
-    its placed arguments, ``key`` the epoch-key stream, ``start_epoch`` the
-    epoch to resume from, and ``split``/``test_x``/``test_y`` what the
-    final evaluation needs."""
+    """A placed paper-mode run: ``epoch(key, st[, participation]) ->
+    (st, losses)`` is the jitted round (``engine.jit_epoch``: state
+    donated, ``data`` bound, ``epoch.jitted`` the ``(key, st, data[,
+    participation])`` function), ``st``/``data`` its placed arguments,
+    ``key`` the epoch-key stream, ``start_epoch`` the epoch to resume
+    from, and ``split``/``test_x``/``test_y`` what the final evaluation
+    needs."""
     epoch: Callable
     st: dict
     data: dict
@@ -180,8 +182,8 @@ def build_paper(*, num_clients=8, batch_size=8, model=None, sharded=False,
             mesh = ED.make_data_mesh(shards)
             print(f"sharded SFLv2: server stream over a {shards}-way mesh "
                   f"({n_dev} device(s)), sequential visitation preserved")
-            fn = lambda k, s, d: ED.sflv2_epoch_sharded(
-                k, s, d, split, opt, opt, mesh=mesh, **common)
+            epoch = ED.make_sflv2_epoch_sharded(split, opt, opt, data,
+                                                mesh=mesh, **common)
         else:
             shards = ED.fit_shards(num_clients, batch_size, alpha=alpha,
                                    collector_mode=collector,
@@ -197,21 +199,21 @@ def build_paper(*, num_clients=8, batch_size=8, model=None, sharded=False,
                   f"wire_dtype={wire_dtype}, wire_dtype_bwd={wire_dtype_bwd}")
             data = ED.shard_client_data(data, mesh)
             st = ED.shard_dcml_state(st, mesh)
-            fn = lambda k, s, d, m=None: ED.sfpl_epoch_sharded(
-                k, s, d, split, opt, opt, mesh=mesh, use_kernel=use_kernel,
+            epoch = ED.make_sfpl_epoch_sharded(
+                split, opt, opt, data, mesh=mesh, use_kernel=use_kernel,
                 alpha=alpha, collector_mode=collector,
                 collector_pipeline=pipeline, collector_submesh=submesh,
                 wire_dtype=wire_dtype, wire_dtype_bwd=wire_dtype_bwd,
-                participation=m, **common)
+                **common)
     elif scheme == "sflv2":
-        fn = lambda k, s, d: E.sflv2_epoch(k, s, d, split, opt, opt,
-                                           **common)
+        epoch = E.jit_epoch(lambda k, s, d: E.sflv2_epoch(
+            k, s, d, split, opt, opt, **common), data,
+            num_clients=num_clients)
     else:
-        fn = lambda k, s, d, m=None: E.sfpl_epoch(
+        epoch = E.jit_epoch(lambda k, s, d, m=None: E.sfpl_epoch(
             k, s, d, split, opt, opt, alpha=alpha, participation=m,
-            **common)
-    return PaperRun(jax.jit(fn, donate_argnums=(1,)), st, data, key,
-                    start_ep, split, ex, ey)
+            **common), data, num_clients=num_clients, alpha=alpha)
+    return PaperRun(epoch, st, data, key, start_ep, split, ex, ey)
 
 
 def train_paper(*, num_clients=8, epochs=4, batch_size=8, sharded=False,
@@ -295,10 +297,7 @@ def train_paper(*, num_clients=8, epochs=4, batch_size=8, sharded=False,
             if wait:
                 time.sleep(wait)
         key, ke = jax.random.split(key)
-        if mask is None:
-            st, losses = run.epoch(ke, st, run.data)
-        else:
-            st, losses = run.epoch(ke, st, run.data, jnp.asarray(mask))
+        st, losses = run.epoch(ke, st, mask)
         mean_losses.append(float(losses.mean()))
         if ep % log_every == 0 or ep == epochs - 1:
             print(f"epoch {ep:3d} loss {mean_losses[-1]:.4f} "
