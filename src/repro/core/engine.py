@@ -179,11 +179,13 @@ def jit_epoch(epoch, data, *, num_clients, alpha=1.0):
     jitted = jax.jit(epoch, donate_argnums=(1,))
 
     def run(key, st, participation=None):
-        if participation is None:
-            return jitted(key, st, data)
-        mask = C.check_participation(num_clients, participation,
-                                     alpha=alpha)
-        return jitted(key, st, data, jnp.asarray(mask))
+        # the dispatch on the profiler's host clock, beside the device ops
+        with jax.profiler.TraceAnnotation("sfpl.epoch"):
+            if participation is None:
+                return jitted(key, st, data)
+            mask = C.check_participation(num_clients, participation,
+                                         alpha=alpha)
+            return jitted(key, st, data, jnp.asarray(mask))
     run.jitted = jitted
     return run
 

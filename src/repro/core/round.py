@@ -701,7 +701,8 @@ def streamed_shuffle(collector, prep, n, produce_group, skip=None):
     ``collector.permute(pool, perm)`` on the synchronous strategy.
     """
     if not isinstance(prep, PreparedPerm):
-        prep = collector.prepare(prep, n)
+        with jax.named_scope("sfpl.shuffle"):
+            prep = collector.prepare(prep, n)
     bounds = collector.group_bounds(n)
     parts, slot = [], None
     for g in range(len(bounds)):
@@ -710,20 +711,25 @@ def streamed_shuffle(collector, prep, n, produce_group, skip=None):
             if skip and skip[g - 1]:
                 passthrough = slot
             else:
-                ticket = collector.issue(slot, prep, g - 1)
+                with jax.named_scope("sfpl.shuffle"):
+                    ticket = collector.issue(slot, prep, g - 1)
+        # outside the shuffle scope: the round scopes its client forward
         rows = produce_group(g)
         if ticket is not None:
-            parts.append(collector.complete(ticket))
+            with jax.named_scope("sfpl.shuffle"):
+                parts.append(collector.complete(ticket))
         elif passthrough is not None:
             parts.append(passthrough)
         slot = rows
     # drain epilogue: the last filled buffer is still in flight
     last = len(bounds) - 1
-    if skip and skip[last]:
-        parts.append(slot)
-    else:
-        parts.append(collector.complete(collector.issue(slot, prep, last)))
-    return collector.assemble(parts, prep, n)
+    with jax.named_scope("sfpl.shuffle"):
+        if skip and skip[last]:
+            parts.append(slot)
+        else:
+            parts.append(collector.complete(collector.issue(slot, prep,
+                                                            last)))
+        return collector.assemble(parts, prep, n)
 
 
 # --------------------------------------------------------------------------
@@ -809,37 +815,44 @@ def sfpl_round(key, st, data, split, opt_c, opt_s, *, num_clients,
         if not any(skip):
             skip = None
 
+    # Every op of a step carries at most one ``sfpl.<phase>`` scope (the
+    # phases are siblings, never nested), so a profile attributes device
+    # time by phase; autodiff marks a phase's backward as
+    # ``transpose(jvp(sfpl.<phase>))``.
     def one_step(carry, idx):
         st, key = carry
-        key, kperm = jax.random.split(key)
-        xb = jax.lax.dynamic_slice_in_dim(data["x"], idx * batch_size,
-                                          batch_size, axis=1)
-        yb = jax.lax.dynamic_slice_in_dim(data["y"], idx * batch_size,
-                                          batch_size, axis=1)
-        y_pool = yb.reshape((n_pool,))
-        perm = collector.make_perm(kperm, n_pool)
-        # routing metadata built ONCE per step from the replicated perm;
-        # the label permute, activation permute, backward exchange, and
-        # (streamed) route_back all reuse it
-        prep = collector.prepare(perm, n_pool)
-        y_shuf = (collector.permute(y_pool, prep, skip=skip) if streamed
-                  else collector.permute(y_pool, prep))
-        mask_c = valid_shuf = None
-        if part is not None:
-            mask_c = part[idx] if per_step_part else part
-            # client-major row mask through the SAME permutation as the
-            # pool; perm is replicated, so this is a local gather
-            valid_shuf = jnp.take(jnp.repeat(mask_c, batch_size), perm)
+        with jax.named_scope("sfpl.client_fwd"):
+            xb = jax.lax.dynamic_slice_in_dim(data["x"], idx * batch_size,
+                                              batch_size, axis=1)
+        with jax.named_scope("sfpl.shuffle"):
+            key, kperm = jax.random.split(key)
+            yb = jax.lax.dynamic_slice_in_dim(data["y"], idx * batch_size,
+                                              batch_size, axis=1)
+            y_pool = yb.reshape((n_pool,))
+            perm = collector.make_perm(kperm, n_pool)
+            # routing metadata built ONCE per step from the replicated
+            # perm; the label permute, activation permute, backward
+            # exchange, and (streamed) route_back all reuse it
+            prep = collector.prepare(perm, n_pool)
+            y_shuf = (collector.permute(y_pool, prep, skip=skip)
+                      if streamed else collector.permute(y_pool, prep))
+            mask_c = valid_shuf = None
+            if part is not None:
+                mask_c = part[idx] if per_step_part else part
+                # client-major row mask through the SAME permutation as
+                # the pool; perm is replicated, so this is a local gather
+                valid_shuf = jnp.take(jnp.repeat(mask_c, batch_size), perm)
         fwd = lambda cp, cs, x: split.client_fwd(cp, cs, x, True, None)
 
         def srv_loss_on(sp, a_shuf):
-            if valid_shuf is None:
-                loss, (nss, _) = split.server_loss(sp, st["sbn"], a_shuf,
-                                                   y_shuf, True, None)
-            else:
-                loss, (nss, _) = split.server_loss(sp, st["sbn"], a_shuf,
-                                                   y_shuf, True, None,
-                                                   valid=valid_shuf)
+            with jax.named_scope("sfpl.server"):
+                if valid_shuf is None:
+                    loss, (nss, _) = split.server_loss(
+                        sp, st["sbn"], a_shuf, y_shuf, True, None)
+                else:
+                    loss, (nss, _) = split.server_loss(
+                        sp, st["sbn"], a_shuf, y_shuf, True, None,
+                        valid=valid_shuf)
             return loss, nss
 
         if streamed and submesh:
@@ -850,14 +863,17 @@ def sfpl_round(key, st, data, split, opt_c, opt_s, *, num_clients,
             # pipeline runs the per-group DENSE collectives over it,
             # each confined to its slice by the plan's axis_index_groups
             # (disjoint slices: all in-flight groups progress at once).
-            A, ncbn = jax.vmap(fwd)(st["cp"], st["cbn"], xb)
-            a_pool = A.reshape((n_pool,) + A.shape[2:])
+            with jax.named_scope("sfpl.client_fwd"):
+                A, ncbn = jax.vmap(fwd)(st["cp"], st["cbn"], xb)
+                a_pool = A.reshape((n_pool,) + A.shape[2:])
             a_shuf = streamed_shuffle(collector, prep, n_pool,
                                       lambda g: a_pool, skip=skip)
             (loss, nsbn), (g_sp, g_shuf) = jax.value_and_grad(
                 srv_loss_on, argnums=(0, 1), has_aux=True)(
                 st["sp"], a_shuf)
-            g_pool = collector.route_back(g_shuf, prep, n_pool, skip=skip)
+            with jax.named_scope("sfpl.shuffle"):
+                g_pool = collector.route_back(g_shuf, prep, n_pool,
+                                              skip=skip)
         elif streamed:
             # 1+2+3 pipelined: the client forward runs flush group by
             # flush group, and each filled group's all_to_all is in
@@ -872,60 +888,72 @@ def sfpl_round(key, st, data, split, opt_c, opt_s, *, num_clients,
                 c0, c1 = cgroups[g]
                 sl = lambda t: jax.tree_util.tree_map(
                     lambda a: a[c0:c1], t)
-                A_g, ncbn_g = jax.vmap(fwd)(sl(st["cp"]), sl(st["cbn"]),
-                                            xb[c0:c1])
-                A_parts.append(A_g)
-                bn_parts.append(ncbn_g)
-                return A_g.reshape((-1,) + A_g.shape[2:])
+                with jax.named_scope("sfpl.client_fwd"):
+                    A_g, ncbn_g = jax.vmap(fwd)(
+                        sl(st["cp"]), sl(st["cbn"]), xb[c0:c1])
+                    A_parts.append(A_g)
+                    bn_parts.append(ncbn_g)
+                    return A_g.reshape((-1,) + A_g.shape[2:])
 
             a_shuf = streamed_shuffle(collector, prep, n_pool,
                                       produce_group, skip=skip)
-            A = _concat_parts(A_parts)
-            ncbn = jax.tree_util.tree_map(
-                lambda *xs: _concat_parts(list(xs)), *bn_parts)
+            with jax.named_scope("sfpl.client_fwd"):
+                A = _concat_parts(A_parts)
+                ncbn = jax.tree_util.tree_map(
+                    lambda *xs: _concat_parts(list(xs)), *bn_parts)
             (loss, nsbn), (g_sp, g_shuf) = jax.value_and_grad(
                 srv_loss_on, argnums=(0, 1), has_aux=True)(
                 st["sp"], a_shuf)
-            g_pool = collector.route_back(g_shuf, prep, n_pool, skip=skip)
+            with jax.named_scope("sfpl.shuffle"):
+                g_pool = collector.route_back(g_shuf, prep, n_pool,
+                                              skip=skip)
         else:
             # 1. client forward, parallel over the (possibly sharded)
             # client axis
-            A, ncbn = jax.vmap(fwd)(st["cp"], st["cbn"], xb)
+            with jax.named_scope("sfpl.client_fwd"):
+                A, ncbn = jax.vmap(fwd)(st["cp"], st["cbn"], xb)
 
-            # 2. global collector: pool client-major (rows inherit the
-            # client sharding, if any) and shuffle per the strategy
-            a_pool = A.reshape((n_pool,) + A.shape[2:])
+                # 2. global collector: pool client-major (rows inherit
+                # the client sharding, if any) and shuffle per the
+                # strategy
+                a_pool = A.reshape((n_pool,) + A.shape[2:])
 
             # 3. ONE server update on the shuffled stack. Differentiating
             # w.r.t. the PRE-shuffle pool makes autodiff emit the
             # de-shuffle (dense scatter or the backward-plan exchange):
             # g_pool arrives already routed back to source clients.
             def srv_loss(sp, a_pool):
-                return srv_loss_on(sp, collector.permute(a_pool, prep))
+                with jax.named_scope("sfpl.shuffle"):
+                    a_shuf = collector.permute(a_pool, prep)
+                return srv_loss_on(sp, a_shuf)
             (loss, nsbn), (g_sp, g_pool) = jax.value_and_grad(
                 srv_loss, argnums=(0, 1), has_aux=True)(st["sp"], a_pool)
-        sp_new, sopt_new = opt_s.update(g_sp, st["sopt"], st["sp"],
-                                        st["step"])
+        with jax.named_scope("sfpl.server_opt"):
+            sp_new, sopt_new = opt_s.update(g_sp, st["sopt"], st["sp"],
+                                            st["step"])
 
         # 4. client backprop, parallel (dA is pooled like A)
-        dA = g_pool.reshape(A.shape)
-        cp_new, copt_new, ncbn2 = jax.vmap(
-            lambda cp, cbn, copt, x, da: client_upd(cp, cbn, copt, x, da,
-                                                    st["step"]))(
-            st["cp"], ncbn, st["copt"], xb, dA)
-        if mask_c is not None:
-            # Absent clients take NO local step: their activation grads
-            # are already exact zeros, but the optimizer would still move
-            # params (weight decay, momentum decay) and the forward still
-            # advanced BN running stats — gate all three back to the
-            # pre-step values so they match a run they never joined.
-            gate = lambda new, old: jax.tree_util.tree_map(
-                lambda nl, ol: jnp.where(
-                    mask_c.reshape((-1,) + (1,) * (nl.ndim - 1)), nl, ol),
-                new, old)
-            cp_new = gate(cp_new, st["cp"])
-            copt_new = gate(copt_new, st["copt"])
-            ncbn2 = gate(ncbn2, st["cbn"])
+        with jax.named_scope("sfpl.client_update"):
+            dA = g_pool.reshape(A.shape)
+            cp_new, copt_new, ncbn2 = jax.vmap(
+                lambda cp, cbn, copt, x, da: client_upd(cp, cbn, copt, x,
+                                                        da, st["step"]))(
+                st["cp"], ncbn, st["copt"], xb, dA)
+            if mask_c is not None:
+                # Absent clients take NO local step: their activation
+                # grads are already exact zeros, but the optimizer would
+                # still move params (weight decay, momentum decay) and the
+                # forward still advanced BN running stats — gate all three
+                # back to the pre-step values so they match a run they
+                # never joined.
+                gate = lambda new, old: jax.tree_util.tree_map(
+                    lambda nl, ol: jnp.where(
+                        mask_c.reshape((-1,) + (1,) * (nl.ndim - 1)), nl,
+                        ol),
+                    new, old)
+                cp_new = gate(cp_new, st["cp"])
+                copt_new = gate(copt_new, st["copt"])
+                ncbn2 = gate(ncbn2, st["cbn"])
 
         st = dict(st, cp=cp_new, cbn=ncbn2, sp=sp_new, sbn=nsbn,
                   copt=copt_new, sopt=sopt_new, step=st["step"] + 1)
@@ -939,13 +967,14 @@ def sfpl_round(key, st, data, split, opt_c, opt_s, *, num_clients,
     # every client — absent clients rejoin on the fresh global model,
     # while their (excluded) local BN stays theirs.
     exclude = bn_mode == "cmsd"
-    w = None
-    if part is not None:
-        epoch_mask = part if part.ndim == 1 else part.any(axis=0)
-        w = epoch_mask.astype(jnp.float32)
-    st = dict(st, cp=fedavg(st["cp"], weights=w, exclude_bn=exclude),
-              cbn=aggregate_bn_state(st["cbn"], aggregate=not exclude,
-                                     weights=w))
+    with jax.named_scope("sfpl.fedavg"):
+        w = None
+        if part is not None:
+            epoch_mask = part if part.ndim == 1 else part.any(axis=0)
+            w = epoch_mask.astype(jnp.float32)
+        st = dict(st, cp=fedavg(st["cp"], weights=w, exclude_bn=exclude),
+                  cbn=aggregate_bn_state(st["cbn"], aggregate=not exclude,
+                                         weights=w))
     return st, losses
 
 

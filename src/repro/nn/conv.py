@@ -19,14 +19,15 @@ def conv2d_init(key, in_ch, out_ch, kernel, *, use_bias=False,
 
 
 def conv2d_apply(params, x, *, stride=1, padding="SAME", compute_dtype=None):
-    w = params["w"]
-    if compute_dtype is not None:
-        w = w.astype(compute_dtype)
-        x = x.astype(compute_dtype)
-    strides = (stride, stride) if isinstance(stride, int) else stride
-    y = jax.lax.conv_general_dilated(
-        x, w, window_strides=strides, padding=padding,
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    if "b" in params:
-        y = y + params["b"].astype(y.dtype)
-    return y
+    with jax.named_scope("conv"):
+        w = params["w"]
+        if compute_dtype is not None:
+            w = w.astype(compute_dtype)
+            x = x.astype(compute_dtype)
+        strides = (stride, stride) if isinstance(stride, int) else stride
+        y = jax.lax.conv_general_dilated(
+            x, w, window_strides=strides, padding=padding,
+            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        if "b" in params:
+            y = y + params["b"].astype(y.dtype)
+        return y

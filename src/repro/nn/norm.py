@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import math
 
+import jax
 import jax.numpy as jnp
 
 from repro.nn.init import ones_init, zeros_init
@@ -62,25 +63,26 @@ def batchnorm_apply(params, state, x, *, training, momentum=0.9, eps=1e-5,
     rows ride along in the pooled batch but must not perturb the moments.
     ``valid=None`` is bit-identical to the dense computation.
     """
-    axes = tuple(range(x.ndim - 1))
-    if training:
-        mean, var = _batch_moments(x, axes, valid)
-        new_state = {
-            "mean": momentum * state["mean"] + (1 - momentum) * mean,
-            "var": momentum * state["var"] + (1 - momentum) * var,
-            "count": state["count"] + 1.0,
-        }
-    else:
-        rmsd = True if use_running_stats is None else use_running_stats
-        if rmsd:
-            mean, var = state["mean"], state["var"]
-        else:  # CMSD: statistics of the batch under test
+    with jax.named_scope("bn"):
+        axes = tuple(range(x.ndim - 1))
+        if training:
             mean, var = _batch_moments(x, axes, valid)
-        new_state = state
-    x32 = x.astype(jnp.float32)
-    y = (x32 - mean) * (1.0 / jnp.sqrt(var + eps))
-    y = y * params["scale"].astype(jnp.float32) + params["bias"].astype(jnp.float32)
-    return y.astype(x.dtype), new_state
+            new_state = {
+                "mean": momentum * state["mean"] + (1 - momentum) * mean,
+                "var": momentum * state["var"] + (1 - momentum) * var,
+                "count": state["count"] + 1.0,
+            }
+        else:
+            rmsd = True if use_running_stats is None else use_running_stats
+            if rmsd:
+                mean, var = state["mean"], state["var"]
+            else:  # CMSD: statistics of the batch under test
+                mean, var = _batch_moments(x, axes, valid)
+            new_state = state
+        x32 = x.astype(jnp.float32)
+        y = (x32 - mean) * (1.0 / jnp.sqrt(var + eps))
+        y = y * params["scale"].astype(jnp.float32) + params["bias"].astype(jnp.float32)
+        return y.astype(x.dtype), new_state
 
 
 def batchnorm_act_apply(params, state, x, *, training, relu=True,
@@ -102,32 +104,33 @@ def batchnorm_act_apply(params, state, x, *, training, relu=True,
     subtract-then-scale at f32 — callers pinning bit-exact f32 parity
     (``policy=None`` in the split model) must keep the unfused path.
     """
-    axes = tuple(range(x.ndim - 1))
-    if training:
-        mean, var = _batch_moments(x, axes, valid)
-        new_state = {
-            "mean": momentum * state["mean"] + (1 - momentum) * mean,
-            "var": momentum * state["var"] + (1 - momentum) * var,
-            "count": state["count"] + 1.0,
-        }
-    else:
-        rmsd = True if use_running_stats is None else use_running_stats
-        if rmsd:
-            mean, var = state["mean"], state["var"]
-        else:  # CMSD: statistics of the batch under test
+    with jax.named_scope("bn"):
+        axes = tuple(range(x.ndim - 1))
+        if training:
             mean, var = _batch_moments(x, axes, valid)
-        new_state = state
-    a = params["scale"].astype(jnp.float32) / jnp.sqrt(var + eps)
-    b = params["bias"].astype(jnp.float32) - mean * a
-    if use_kernel:
-        from repro.kernels.bn_act import ops as _ops
-        y = _ops.bn_act(x, a, b, relu=relu, interpret=interpret)
-    else:
-        y32 = x.astype(jnp.float32) * a + b
-        if relu:
-            y32 = jnp.maximum(y32, 0.0)
-        y = y32.astype(x.dtype)
-    return y, new_state
+            new_state = {
+                "mean": momentum * state["mean"] + (1 - momentum) * mean,
+                "var": momentum * state["var"] + (1 - momentum) * var,
+                "count": state["count"] + 1.0,
+            }
+        else:
+            rmsd = True if use_running_stats is None else use_running_stats
+            if rmsd:
+                mean, var = state["mean"], state["var"]
+            else:  # CMSD: statistics of the batch under test
+                mean, var = _batch_moments(x, axes, valid)
+            new_state = state
+        a = params["scale"].astype(jnp.float32) / jnp.sqrt(var + eps)
+        b = params["bias"].astype(jnp.float32) - mean * a
+        if use_kernel:
+            from repro.kernels.bn_act import ops as _ops
+            y = _ops.bn_act(x, a, b, relu=relu, interpret=interpret)
+        else:
+            y32 = x.astype(jnp.float32) * a + b
+            if relu:
+                y32 = jnp.maximum(y32, 0.0)
+            y = y32.astype(x.dtype)
+        return y, new_state
 
 
 # --------------------------------------------------------------------------
