@@ -48,7 +48,8 @@ def program_rounds(cell, seeds, fault=None, **build):
     out = {}
     if not seeds:
         return out
-    with faults.planted(fault) if fault else contextlib.nullcontext():
+    with (faults.planted(fault, S.family(cell["config"])) if fault
+          else contextlib.nullcontext()):
         prog = R.Program(cell, **build)
         for s in seeds:
             prog.start(s)

@@ -1,8 +1,9 @@
 """Faults planted in the program under test, for the correctness check's
 own tests and for reading each fault's numbers on the chip
 (``calibrate.py``). Each is a context manager that patches the program's
-module attribute that the launcher looks up when it builds (and traces)
-the round, and restores it on exit:
+module attribute that the family's ``build`` looks up when it builds (and
+traces) the round, and restores it on exit: ``round.sfpl_round``, or the
+split builder that the family names (``SPLIT``):
 
 * ``state_unchanged`` — the round returns the state it was given (and the
   losses it computed);
@@ -18,6 +19,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import importlib
 
 import jax
 import jax.numpy as jnp
@@ -34,19 +36,20 @@ _misroute.defvjp(lambda a: (a, None),
                  lambda _, g: (jnp.roll(g, 1, axis=0),))
 
 
-def _wrap_split(mutate):
-    from repro.core import engine as E
-    orig = E.make_resnet_split
+def _wrap_split(family, mutate):
+    module, attr = family.SPLIT
+    mod = importlib.import_module(module)
+    orig = getattr(mod, attr)
 
-    def make(cfg, policy=None):
-        s = orig(cfg, policy=policy)
+    def make(*args, **kw):
+        s = orig(*args, **kw)
 
         def server_loss(sp, ss, a, y, training=True, rmsd=None,
                         valid=None):
             a, y = mutate(a, y)
             return s.server_loss(sp, ss, a, y, training, rmsd)
         return dataclasses.replace(s, server_loss=server_loss)
-    return E, "make_resnet_split", make
+    return mod, attr, make
 
 
 def _half(a, y):
@@ -55,7 +58,8 @@ def _half(a, y):
 
 
 @contextlib.contextmanager
-def planted(name):
+def planted(name, family):
+    """``name`` planted in the program of ``family`` (``spec.family``)."""
     from repro.core import round as RD
     if name == "state_unchanged":
         orig = RD.sfpl_round
@@ -65,9 +69,9 @@ def planted(name):
             return st, losses
         mod, attr, new = RD, "sfpl_round", sfpl_round
     elif name == "half_batch":
-        mod, attr, new = _wrap_split(_half)
+        mod, attr, new = _wrap_split(family, _half)
     elif name == "misroute":
-        mod, attr, new = _wrap_split(lambda a, y: (_misroute(a), y))
+        mod, attr, new = _wrap_split(family, lambda a, y: (_misroute(a), y))
     else:
         raise ValueError(f"unknown fault {name!r}; have {FAULTS}")
     old = getattr(mod, attr)

@@ -1,7 +1,7 @@
 """Share of device busy time, in %, of the ops inside a ``conv`` scope
 (``nn.conv.conv2d_apply``, forward and backward, client and server),
 averaged over the chips; ``None`` without the round's scope map
-(``scopes.of_ctx``)."""
+(``scopes.of_program``)."""
 from chip import scopes as SC
 
 

@@ -4,17 +4,20 @@
         --seconds <s> --trace <0|1>
 
 Set-up (``setup_s``): build the cell's round through the program's own
-launcher (``repro.launch.train.build_paper``), make the state and the
-client data on the device from ``--seed`` and place them in the layout the
-launcher chose, then drive the jitted round (``PaperRun.epoch.jitted``:
-4 local steps and the FedAvg) twice. The first call compiles (or loads
-from the compile cache) and is the round that the correctness check
+launcher, as the configuration's family says (``families/<family>.py``
+``build``), make the state and the client data on the device from
+``--seed`` (the family's ``make_inputs``) and place them in the layout
+the launcher chose, then drive the jitted round (``.epoch.jitted``: the
+round's local steps and the FedAvg) twice. The first call compiles (or
+loads from the compile cache) and is the round that the correctness check
 compares; the second proves the window's call signature warm.
 
 Window: rounds back to back for ``--seconds``, one ``block_until_ready``
 per round, each round with its own key. ``--trace 1`` records the window
 with the profiler and reports the per-layer metrics instead of the
-end-to-end ones.
+end-to-end ones; after the window, the round's executable, lowered on the
+live state and data, gives the scope map that the scope readers read
+(``scopes.of_program``).
 
 After the window: the device's peak memory is read, the program's state is
 dropped, and the plain reference (``configs/<reference>``) recomputes the
@@ -57,6 +60,7 @@ import numpy as np  # noqa: E402
 
 from chip import flops as F  # noqa: E402
 from chip import gen  # noqa: E402
+from chip import scopes as SC  # noqa: E402
 from chip import spec as S  # noqa: E402
 from chip import trace as TR  # noqa: E402
 
@@ -81,21 +85,6 @@ def require_chips(chips):
                          f"{len(devs)}")
 
 
-def build_kwargs(cfg, traffic):
-    """The launcher's arguments for a configuration and a traffic mix."""
-    m, fl, opt = cfg["model"], cfg["fleet"], cfg["optimizer"]
-    if (m["depth"], m["width"], m["input_hw"]) == (8, 8, 8):
-        model = None            # the launcher's small CPU-sized ResNet-8
-    else:
-        model = f"resnet{m['depth']}"
-    return dict(num_clients=fl["num_clients"],
-                batch_size=fl["per_client_batch"], model=model,
-                lr=opt["lr"], sharded=traffic["engine"] == "sharded",
-                compute_dtype=traffic["compute_dtype"],
-                wire_dtype=traffic["wire_dtype"],
-                pipeline=traffic["pipeline"], alpha=traffic["alpha"])
-
-
 def _shapes(tree):
     return jax.tree_util.tree_map(lambda a: (a.shape, str(a.dtype)), tree)
 
@@ -111,13 +100,13 @@ class Program:
     def __init__(self, cell, **build):
         """``build`` overrides the launcher's arguments (calibration's
         looks only)."""
-        from repro.launch import train as T
         cfg, traffic = cell["config"], cell["traffic"]
         self.cfg, self.traffic = cfg, traffic
+        self.family = S.family(cfg)
         self.phase_s = {"start": time.perf_counter() - T_START}
         t = time.perf_counter()
         with jax.profiler.TraceAnnotation("bench.build"):
-            run = T.build_paper(**build_kwargs(cfg, traffic) | build)
+            run = self.family.build(cfg, traffic, **build)
             self.st_layout = jax.tree_util.tree_map(lambda a: a.sharding,
                                                     run.st)
             self.data_layout = jax.tree_util.tree_map(lambda a: a.sharding,
@@ -131,13 +120,10 @@ class Program:
         """State and data from ``seed``; the first round (compiled here,
         or loaded from the cache, and kept for the correctness check) and
         a second one."""
-        cfg = self.cfg
         t = time.perf_counter()
         with jax.profiler.TraceAnnotation("bench.inputs"):
             key = gen.seed_key(seed)
-            st, data = gen.make_inputs(
-                key, model=gen.items(cfg["model"]),
-                fleet=gen.items(cfg["fleet"]), data=gen.items(cfg["data"]))
+            st, data = self.family.make_inputs(key, self.cfg)
             if _shapes(st) != self.want:
                 raise SystemExit("the benchmark's state does not match the "
                                  f"program's layout: {_shapes(st)} != "
@@ -160,10 +146,13 @@ class Program:
         self.phase_s["second_round"] = time.perf_counter() - t
         return self
 
+    def args(self):
+        """The next round's arguments: its key, the state and the data."""
+        return self.keys[self.rounds % N_KEYS], self.st, self.data
+
     def step(self):
         with jax.profiler.TraceAnnotation("bench.round.dispatch"):
-            self.st, losses = self.fn(self.keys[self.rounds % N_KEYS],
-                                      self.st, self.data)
+            self.st, losses = self.fn(*self.args())
         with jax.profiler.TraceAnnotation("bench.round.wait"):
             jax.block_until_ready((self.st, losses))
         self.rounds += 1
@@ -197,9 +186,7 @@ def reference_round(cell, seed, mode="f32"):
     params after, step-0 gradient norm of each leaf), all on the host."""
     cfg = cell["config"]
     ref = S.reference(cfg)
-    st, data = gen.make_inputs(
-        gen.seed_key(seed), model=gen.items(cfg["model"]),
-        fleet=gen.items(cfg["fleet"]), data=gen.items(cfg["data"]))
+    st, data = S.family(cfg).make_inputs(gen.seed_key(seed), cfg)
     params = {"cp": st["cp"], "sp": st["sp"]}
     del st
     out = ref.sfpl_round(
@@ -295,12 +282,15 @@ def p90(xs):
 
 
 class Ctx:
-    """What a per-layer metric reader gets."""
+    """What a per-layer metric reader gets; ``scopes`` is the window
+    executable's scope map (``scopes.of_program``), or ``None``."""
 
-    def __init__(self, cell, tr, lo, hi, rounds, peaks):
+    def __init__(self, cell, tr, lo, hi, rounds, peaks, scopes=None):
         self.config, self.traffic = cell["config"], cell["traffic"]
         self.chips = cell["chips"]
+        self.family = S.family(self.config)
         self.peaks, self.flops, self.tr = peaks, F, tr
+        self.scopes = scopes
         self.lo, self.hi = lo, hi
         self.window_ns = hi - lo
         self.window_s = self.window_ns / 1e9
@@ -354,6 +344,7 @@ def run_cell(cell, seed, seconds, trace=False, peaks=None,
     if prog.compiled_in_window:
         log(f"warning: {prog.compiled_in_window} compile(s) inside the "
             f"window")
+    scopes = SC.of_program(prog) if trace else None
     first, p1 = prog.first_losses, prog.p1
     prog.free()
 
@@ -376,7 +367,9 @@ def run_cell(cell, seed, seconds, trace=False, peaks=None,
         tr = TR.load(str(TRACE_DIR))
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
         lo, hi = TR.span(tr, "bench.window")
-        ctx = Ctx(cell, tr, lo, hi, rounds, peaks)
+        ctx = Ctx(cell, tr, lo, hi, rounds, peaks, scopes)
+        if scopes is not None:
+            SC.log_breakdown(ctx)
         for m in cell["per_layer"]:
             v = S.reader(m["name"]).read(ctx)
             if v is not None:

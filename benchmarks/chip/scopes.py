@@ -7,14 +7,14 @@ round's phase (``sfpl.<phase>``) and the layer kind (``conv``, ``bn``)
 from it. A TPU trace names each device op by its whole instruction text,
 so ``scope_ns`` can sum the device time of ``trace.load``'s ops by scope.
 
-A per-layer reader gets the map through ``of_ctx``: the first reader of a
-traced run builds the cell's round again through the launcher, with the
-harness's own arguments, and takes the map from its compiled executable,
-which is the one the window ran (the same program, loaded from the
-compile cache). This runs after the window, so neither the window nor
-set-up moves, and an untraced run does none of it. Where the program
-names no ``sfpl.`` scope (a program without them), the readers read
-nothing.
+A per-layer reader gets the map as its context's ``scopes``: after the
+window of a traced run, and before the program's state is freed,
+``of_program`` lowers the window's jitted round on its live state and data
+and takes the map from the compiled executable, the one the window ran
+(loaded from the compile cache; the round is not built a second time).
+Neither the window nor set-up moves, and an untraced run does none of it.
+Where the program names no ``sfpl.`` scope (a program without them), the
+readers read nothing.
 """
 from __future__ import annotations
 
@@ -33,7 +33,6 @@ _OP_NAME = re.compile(r'\bop_name="([^"]*)"')
 _WRAPPED = re.compile(r"^[\w.\-]*\((.*)\)$")
 PHASE_PREFIX = "sfpl."
 KINDS = ("conv", "bn")
-_UNSET = object()
 
 
 def log(*a):
@@ -203,56 +202,29 @@ def idle_gaps(tr, lo, hi, scopes, k=10):
     return out
 
 
-def round_text(config, traffic):
-    """The optimized HLO text of a cell's round as the window runs it:
-    built through the launcher with the harness's arguments, lowered on
-    state and data placed in the launcher's layout (committed, as
-    ``run.Program`` places them, so that the module and its compile-cache
-    key are the window's) and a key of the window's type, and compiled
-    (loaded from the compile cache)."""
-    import jax
-    import numpy as np
-
-    from chip import gen
-    from chip import run as R
-    from repro.launch import train as T
-    run = T.build_paper(**R.build_kwargs(config, traffic))
-    place = lambda t: jax.device_put(
-        t, jax.tree_util.tree_map(lambda a: a.sharding, t))
-    key = np.asarray(jax.random.split(gen.seed_key(0), 2))[0]
-    text = run.epoch.jitted.lower(key, place(run.st), place(run.data)) \
-        .compile().as_text()
-    del run
-    return text
-
-
-def of_ctx(ctx):
-    """The scope map of a reader context's round, made once a run (the
-    context keeps it as ``scopes``; a test may set it there, ``None``
-    meaning no map), or ``None`` where it cannot be made."""
-    if getattr(ctx, "scopes", _UNSET) is _UNSET:
-        t = time.perf_counter()
-        try:
-            ctx.scopes = from_text(round_text(ctx.config, ctx.traffic))
-        except Exception:                       # a reader never raises
-            log("scope map: not made\n" + traceback.format_exc())
-            ctx.scopes = None
-        else:
-            log(f"scope map: {len(ctx.scopes['ops'])} instructions of "
-                f"module {ctx.scopes['module']} in "
-                f"{time.perf_counter() - t!r} s")
-            log_breakdown(ctx)
-    return ctx.scopes
+def of_program(prog):
+    """The scope map of the executable that ``prog``'s window ran (a
+    ``run.Program`` before ``free``): its jitted round lowered on the
+    next round's arguments and compiled, a compile-cache hit; ``None``
+    where it cannot be made, which a traced run logs and reads past."""
+    t = time.perf_counter()
+    try:
+        scopes = from_text(prog.fn.lower(*prog.args()).compile().as_text())
+    except Exception:           # the run goes on, its map unread
+        log("scope map: not made\n" + traceback.format_exc())
+        return None
+    log(f"scope map: {len(scopes['ops'])} instructions of module "
+        f"{scopes['module']} in {time.perf_counter() - t!r} s")
+    return scopes
 
 
 def ns_of(ctx):
     """``scope_ns`` of the context's window, once a run, or ``None``
     without a scope map."""
-    scopes = of_ctx(ctx)
-    if scopes is None:
+    if ctx.scopes is None:
         return None
     if getattr(ctx, "scope_ns", None) is None:
-        ctx.scope_ns = scope_ns(ctx.tr, ctx.lo, ctx.hi, scopes)
+        ctx.scope_ns = scope_ns(ctx.tr, ctx.lo, ctx.hi, ctx.scopes)
     return ctx.scope_ns
 
 

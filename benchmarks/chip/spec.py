@@ -2,6 +2,15 @@
 
 * a configuration ``<config>`` is ``configs/<config>.json``, and its plain
   reference the module that the file's ``reference`` key names, beside it;
+* the model behind a configuration is ``families/<family>.py``, by the
+  file's ``family`` key: the only module of the harness that knows the
+  model. It gives ``build(cfg, traffic, **overrides)``, the round through
+  the program's own launcher (``.st``, ``.data`` and the jitted round
+  ``.epoch.jitted``); ``make_inputs(key, cfg)``, the state in the
+  program's layout and the client data; ``train_flops_per_sample(model)``;
+  ``SPLIT``, the (module, attribute) of the program's split builder, which
+  ``faults.py`` patches; and ``tiny(cfg)``, the configuration at CPU test
+  size;
 * a traffic mix ``<traffic>`` is ``traffic/<traffic>.json``;
 * a cell ``<cell>`` keeps the limits of its correctness check, with the
   readings they were set from, in ``workloads/<cell>.json``;
@@ -11,8 +20,10 @@
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
+import sys
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
@@ -28,10 +39,13 @@ def benchmark():
     return _json(REPO / "BENCHMARK.json")
 
 
+@functools.cache
 def _module(path):
-    spec = importlib.util.spec_from_file_location(
-        "bench_" + path.stem.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
+    """The module at ``path``, loaded once a process and registered as
+    ``bench_<directory>_<stem>``, so that a fault can patch it by name."""
+    name = f"bench_{path.parent.name}_{path.stem}".replace(".", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = sys.modules[name] = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
 
@@ -42,6 +56,10 @@ def config(name):
 
 def reference(cfg):
     return _module(HERE / "configs" / cfg["reference"])
+
+
+def family(cfg):
+    return _module(HERE / "families" / f"{cfg['family']}.py")
 
 
 def traffic(name):

@@ -5,10 +5,11 @@ small configuration: the sound f32 program passes its cells' limits, and
 each fault that a cell's numbers catch on every chip seed (the
 ``coverage`` its ``workloads/<cell>.json`` records), planted in the
 program underneath (``faults.py``), makes ``correct`` come out false under
-that cell's limits. The runs use ResNet-8 at width 8 on 8x8 inputs. The
-control, the reference computed in the precision below the one a cell's
-traffic states, fails every cell's limits with the cell's own model and 2
-of its clients, 8 rows each.
+that cell's limits. The runs use the configuration at its family's CPU
+test size (``tiny``: ResNet-8 at width 8 on 8x8 inputs). The control, the
+reference computed in the precision below the one a cell's traffic
+states, fails every cell's limits with the cell's own model and 2 of its
+clients, 8 rows each.
 """
 import json
 
@@ -24,17 +25,14 @@ SEED = 2 ** 33 + 5
 
 
 def tiny(cell):
-    """The cell on ResNet-8 at width 8, 8x8 inputs, 4 clients x 4 rows."""
-    cfg = json.loads(json.dumps(cell["config"]))
-    cfg["model"].update(depth=8, width=8, input_hw=8, num_classes=4)
-    cfg["fleet"].update(num_clients=4, per_client_batch=4)
-    return dict(cell, config=cfg)
+    """The cell at its family's CPU test size."""
+    return dict(cell, config=S.family(cell["config"]).tiny(cell["config"]))
 
 
 def run(cell, fault=None):
     if fault is None:
         return R.run_cell(cell, SEED, 0.05, configure_jax=False)
-    with faults.planted(fault):
+    with faults.planted(fault, S.family(cell["config"])):
         return R.run_cell(cell, SEED, 0.05, configure_jax=False)
 
 

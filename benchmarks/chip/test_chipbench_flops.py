@@ -1,4 +1,6 @@
-"""Operation and byte counts from shapes, checked by hand."""
+"""Operation and byte counts from shapes, checked by hand: the ResNet
+family's (``families/resnet_cifar.py``) and those every family shares
+(``flops.py``)."""
 import pytest
 
 from chip import flops as F
@@ -6,22 +8,23 @@ from chip import spec as S
 
 R32 = S.config("resnet32_cifar10")
 R56 = S.config("resnet56_cifar100")
+RC = S.family(R32)
 
 
 @pytest.mark.parametrize("cfg", [R32, R56], ids=["r32", "r56"])
 def test_client_part_matches_table_iv(cfg):
     # the paper's Table IV: 475.136K per data point for the client part
-    assert F.table_iv_client(cfg["model"]) == 475_136
+    assert RC.table_iv_client(cfg["model"]) == 475_136
 
 
 def test_one_residual_stage_by_hand():
     # ResNet-32, stage 0: five blocks of two 3x3 16->16 convs at 32x32
-    stage0 = sum(F.conv_macs(hw, k, ci, co)
-                 for s, hw, k, ci, co in F.stage_convs(R32["model"])
+    stage0 = sum(RC.conv_macs(hw, k, ci, co)
+                 for s, hw, k, ci, co in RC.stage_convs(R32["model"])
                  if s == 0)
     assert stage0 == 10 * 32 * 32 * 9 * 16 * 16 == 23_592_960
     # stage 1 opens with a strided 3x3 16->32 conv and a 1x1 projection
-    s1 = [c for c in F.stage_convs(R32["model"]) if c[0] == 1]
+    s1 = [c for c in RC.stage_convs(R32["model"]) if c[0] == 1]
     assert s1[:3] == [(1, 16, 3, 16, 32), (1, 16, 3, 32, 32),
                       (1, 16, 1, 16, 32)]
     assert len(s1) == 11
@@ -35,9 +38,9 @@ def test_forward_macs_by_hand():
     for cfg, n, classes in ((R32, 5, 10), (R56, 9, 100)):
         want = (conv(32, 3, 3, 16) + stage(32, 16, 16, n)
                 + stage(16, 16, 32, n) + stage(8, 32, 64, n) + 64 * classes)
-        assert F.forward_macs(cfg["model"]) == want
-    assert F.forward_macs(R32["model"]) == 69_124_736
-    assert F.train_flops_per_sample(R32["model"]) == 6 * 69_124_736
+        assert RC.forward_macs(cfg["model"]) == want
+    assert RC.forward_macs(R32["model"]) == 69_124_736
+    assert RC.train_flops_per_sample(R32["model"]) == 6 * 69_124_736
 
 
 def test_bn_act_cost_by_hand():
@@ -52,13 +55,14 @@ def test_bn_act_cost_by_hand():
     rows = 6
     ops = sum(rows * e * k for e, _, k in layers) * 2
     nbytes = sum(2 * rows * e * 2 + 2 * c * 4 for e, c, _ in layers) * 2
-    assert F.bn_act_cost(model, fleet, "bfloat16") == (ops, nbytes)
+    assert RC.bn_act_cost(model, fleet, "bfloat16") == (ops, nbytes)
 
 
 def test_permute_cost_by_hand():
     # 1,600 rows over 4 chips; a bf16 row is 32*32*16*2 bytes; 4 kernels
     # a step each read and write the chip's 400 rows; 4 steps
-    got = F.permute_cost(R56["model"], R56["fleet"], 4, "bfloat16")
+    got = F.permute_cost(RC.smashed_elems(R56["model"]), R56["fleet"], 4,
+                         "bfloat16")
     assert got == (0, 4 * 4 * 2 * 400 * 32 * 32 * 16 * 2)
 
 

@@ -1,12 +1,13 @@
 """The scope reductions (``scopes.py``): ``scope_map`` on HLO text that the
 CPU compiles from a tiny round with the program's phase and kind scopes,
 the eight scope readers on a hand trace whose op names come from that map,
-the ``@phase`` label of an idle gap, the round's map rebuilt from a cell
-as the window ran it, and the readers that were there before, which read
-on ``testdata/trace_small.json`` exactly what they read before the scopes
-came."""
+the ``@phase`` label of an idle gap, the map of the executable that a
+traced run's window ran, and the readers that were there before, which
+read on ``testdata/trace_small.json`` exactly what they read before the
+scopes came."""
 import json
 import math
+import types
 from pathlib import Path
 
 import jax
@@ -14,6 +15,7 @@ import jax.numpy as jnp
 import pytest
 
 from chip import flops as F
+from chip import gen
 from chip import run as R
 from chip import scopes as SC
 from chip import spec as S
@@ -249,44 +251,55 @@ def test_idle_gap_named_by_the_phase_that_ends_it(scopes):
 
 
 def tiny_cell():
-    """``r32_c10.f32`` on ResNet-8 at width 8, 8x8 inputs, 4 clients x 4
-    rows."""
+    """``r32_c10.f32`` at its family's CPU test size."""
     cell = S.cell("r32_c10.f32")
-    cfg = json.loads(json.dumps(cell["config"]))
-    cfg["model"].update(depth=8, width=8, input_hw=8, num_classes=4)
-    cfg["fleet"].update(num_clients=4, per_client_batch=4)
-    return dict(cell, config=cfg)
+    return dict(cell, config=S.family(cell["config"]).tiny(cell["config"]))
 
 
-def test_the_map_is_that_of_the_executable_the_window_ran():
-    """``of_ctx`` builds the round again from the cell and maps the
-    executable that ``run.Program``'s window runs: the same instructions
-    under the same scopes (the text differs only in the source lines of
-    its stack frames)."""
-    cell = tiny_cell()
-    prog = R.Program(cell).start(2 ** 33 + 5)
-    window = prog.fn.lower(prog.keys[prog.rounds], prog.st,
-                           prog.data).compile().as_text()
-    prog.free()
-    again = SC.round_text(cell["config"], cell["traffic"])
-    assert SC.from_text(again) == SC.from_text(window)
-    ctx = R.Ctx(cell, {"devices": {}, "spans": []}, 0, 1, 1,
-                F.peaks("TPU v5 lite"))
-    got = SC.of_ctx(ctx)
-    assert got == SC.from_text(window) and ctx.scopes is got
+def test_the_map_is_that_of_the_executable_the_window_ran(monkeypatch):
+    """A traced run maps the executable that its window ran: the jitted
+    round lowered on the live state and data after the window, the same
+    instructions under the same scopes as the round's first call, placed
+    as ``run.Program.start`` places it; the round is built once."""
+    cell, seed = tiny_cell(), 2 ** 33 + 5
+    fam = S.family(cell["config"])
+    builds, maps = [], []
+    build, of_program = fam.build, SC.of_program
+
+    def counted(*a, **k):
+        builds.append(a)
+        return build(*a, **k)
+
+    def kept(prog):
+        st, data = fam.make_inputs(gen.seed_key(seed), prog.cfg)
+        first = prog.fn.lower(prog.keys[0],
+                              jax.device_put(st, prog.st_layout),
+                              jax.device_put(data, prog.data_layout))
+        maps.append((of_program(prog),
+                     SC.from_text(first.compile().as_text())))
+        return maps[-1][0]
+    monkeypatch.setattr(fam, "build", counted)
+    monkeypatch.setattr(SC, "of_program", kept)
+    res = R.run_cell(cell, seed, 0.05, trace=True,
+                     peaks=F.peaks("TPU v5 lite"), configure_jax=False)
+    assert res["correct"], res["checks"]
+    assert len(builds) == 1
+    [(got, first)] = maps
+    assert got == first
     phases = {SC.phase_of(op) for op in got["ops"].values()}
     assert ("server", "bwd") in phases and ("fedavg", "fwd") in phases
-    # made once a run, and a reader on an empty trace reads nothing
-    assert SC.of_ctx(ctx) is got
-    assert S.reader("round.server_fwd.ms").read(ctx) is None
+    # the CPU has no device plane: the scope readers read nothing
+    assert "round.server_fwd.ms" not in res["metrics"]
 
 
 def test_no_map_where_the_round_cannot_be_built():
-    cell = tiny_cell()
-    ctx = R.Ctx(cell, {"devices": {}, "spans": []}, 0, 1, 1,
+    """A program whose executable cannot be made (here, one freed) gives
+    no map and raises nothing, and the scope readers read nothing."""
+    freed = types.SimpleNamespace(fn=None, args=lambda: ())
+    assert SC.of_program(freed) is None
+    ctx = R.Ctx(tiny_cell(), {"devices": {}, "spans": []}, 0, 1, 1,
                 F.peaks("TPU v5 lite"))
-    ctx.config = dict(ctx.config, model={})
-    assert SC.of_ctx(ctx) is None
+    assert ctx.scopes is None
     assert S.reader("model.conv.share").read(ctx) is None
 
 

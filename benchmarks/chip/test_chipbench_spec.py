@@ -53,6 +53,10 @@ def test_configs_resolve_and_are_used():
         cfg = S.config(c["name"])
         assert cfg["name"] == c["name"]
         assert hasattr(S.reference(cfg), "sfpl_round")
+        fam = S.family(cfg)
+        for attr in ("build", "make_inputs", "train_flops_per_sample",
+                     "SPLIT", "tiny"):
+            assert hasattr(fam, attr), (cfg["family"], attr)
         for k in c["reduced"]:
             assert k in cfg and NAME.match(k)
 

@@ -84,8 +84,8 @@ def test_readers_on_the_hand_trace(tr):
                         100 * (1 - (550 + 400) / 2 / 1000))
     # the BN epilogue kernel runs in bf16 traffic only
     ctx.traffic = dict(ctx.traffic, compute_dtype="bfloat16")
-    ops, nbytes = F.bn_act_cost(ctx.config["model"], ctx.config["fleet"],
-                                "bfloat16")
+    ops, nbytes = ctx.family.bn_act_cost(ctx.config["model"],
+                                         ctx.config["fleet"], "bfloat16")
     least = nbytes / 819e9
     assert math.isclose(S.reader("bn_act_roofline").read(ctx),
                         100 * least * 2 / 100e-9)
@@ -101,7 +101,8 @@ def test_mfu_reader():
     ctx = _ctx({"devices": {}, "spans": []}, "r32_c10.f32", rounds=10)
     ctx.window_s = 2.0
     rate = 10 * 640 * 4 / 2.0
-    want = 100 * 6 * F.forward_macs(ctx.config["model"]) * rate / 197e12
+    want = (100 * 6 * ctx.family.forward_macs(ctx.config["model"]) * rate
+            / 197e12)
     assert math.isclose(S.reader("model_step.mfu").read(ctx), want)
 
 
